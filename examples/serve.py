@@ -26,6 +26,7 @@ sys.path.insert(
 )
 from common import make_requests  # noqa: E402
 
+from repro.launch import compile_cache
 from repro.models import ModelConfig
 from repro.models.model import init_params
 from repro.serve import (
@@ -104,6 +105,7 @@ def main():
                          "SSD (mamba2-130m, mamba2_tiny), RG-LRU hybrid "
                          "(recurrentgemma-2b, hybrid_tiny)")
     args = ap.parse_args()
+    compile_cache.enable()
 
     if args.arch:
         from repro.configs import get_smoke_config

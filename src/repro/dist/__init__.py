@@ -1,6 +1,6 @@
 """repro.dist — the distribution API for the whole codebase.
 
-* ``repro.dist.mesh``     — mesh construction + jax 0.4/0.5 compat seam.
+* ``repro.dist.mesh``     — mesh construction (Auto axis types).
 * ``repro.dist.sharding`` — path-based sharding rules (params, opt state,
   decode caches, batches).
 * ``repro.dist.api``      — ``Distribution``: mesh + rules + donation, and

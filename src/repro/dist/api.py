@@ -37,19 +37,22 @@ PyTree = Any
 class StepBundle:
     """A jitted SPMD step plus everything needed to run or lower it."""
 
-    fn: Callable  # jitted; call under ``with bundle`` or directly
+    fn: Callable  # jitted; call through the bundle, which sets the mesh
     mesh: Any
     abstract_inputs: Tuple  # ShapeDtypeStructs accepted by ``fn``
     in_shardings: Tuple
     out_shardings: Any
     opt: Any = None  # train steps carry their optimizer
 
+    # ``jax.set_mesh`` makes the mesh visible to the model code while it
+    # traces (``models.moe._current_mesh``): activation constraints and the
+    # shard_map'd MoE layer read it there.
     def __call__(self, *args):
-        with self.mesh:
+        with jax.set_mesh(self.mesh):
             return self.fn(*args)
 
     def lower(self):
-        with self.mesh:
+        with jax.set_mesh(self.mesh):
             return self.fn.lower(*self.abstract_inputs)
 
 

@@ -10,40 +10,26 @@ Everything here is a function, never a module-level constant: importing
 this module must not touch jax device state (the dry-run sets XLA_FLAGS
 before the first backend init).
 
-``make_mesh`` is the jax-0.4/0.5 compat seam: jax >= 0.5 grew
-``jax.sharding.AxisType`` and an ``axis_types=`` kwarg on
-``jax.make_mesh``; on 0.4.x neither exists and every axis is implicitly
-Auto.  Callers (tests included) go through this helper so the same code
-runs on both.
+Every mesh is built with Auto axis types, so the compiler propagates
+shardings from the committed inputs and the constraints the model code
+places (``models.transformer.constrain_activations``).
 """
 from __future__ import annotations
 
 from typing import Optional, Sequence, Tuple
 
 import jax
-
-
-def axis_types_kwargs(n_axes: int) -> dict:
-    """``{"axis_types": (AxisType.Auto,) * n}`` on jax >= 0.5, ``{}`` on 0.4."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
+from jax.sharding import AxisType
 
 
 def make_mesh(
     axis_shapes: Sequence[int], axis_names: Sequence[str], *, devices=None
 ):
-    """``jax.make_mesh`` with Auto axis types where the kwarg exists."""
-    kwargs = axis_types_kwargs(len(axis_names))
-    if devices is not None:
-        kwargs["devices"] = devices
-    try:
-        return jax.make_mesh(tuple(axis_shapes), tuple(axis_names), **kwargs)
-    except TypeError:
-        # jax builds where make_mesh predates axis_types / devices kwargs
-        kwargs.pop("axis_types", None)
-        return jax.make_mesh(tuple(axis_shapes), tuple(axis_names), **kwargs)
+    """``jax.make_mesh`` with Auto axis types."""
+    return jax.make_mesh(
+        tuple(axis_shapes), tuple(axis_names),
+        axis_types=(AxisType.Auto,) * len(axis_names), devices=devices,
+    )
 
 
 def make_production_mesh(*, multi_pod: bool = False):

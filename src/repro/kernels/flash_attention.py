@@ -20,6 +20,7 @@ import math
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 DEFAULT_BLOCK_Q = 128
 DEFAULT_BLOCK_K = 128
@@ -54,12 +55,13 @@ def _attn_kernel(
         lo = 0
 
     if segmented:
-        qseg = q_seg_ref[...]  # (block_q,)
+        qseg = q_seg_ref[...]  # (block_q, 1): a column, keys' ids are a row
 
     def body(ki, carry):
         acc, m_prev, l_prev = carry
-        k_tile = pl.load(k_ref, (pl.dslice(ki * block_k, block_k), slice(None)))
-        v_tile = pl.load(v_ref, (pl.dslice(ki * block_k, block_k), slice(None)))
+        start = pl.multiple_of(ki * block_k, block_k)
+        k_tile = k_ref[pl.ds(start, block_k), :]
+        v_tile = v_ref[pl.ds(start, block_k), :]
         s = jnp.dot(q, k_tile.astype(jnp.float32).T)  # (bq, bk)
 
         kpos = ki * block_k + jax.lax.iota(jnp.int32, block_k)
@@ -69,8 +71,8 @@ def _attn_kernel(
         if window > 0:
             mask &= kpos[None, :] > qpos[:, None] - window
         if segmented:
-            kseg = pl.load(kv_seg_ref, (pl.dslice(ki * block_k, block_k),))
-            mask &= qseg[:, None] == kseg[None, :]
+            kseg = kv_seg_ref[:, pl.ds(start, block_k)]  # (1, block_k)
+            mask &= qseg == kseg
         s = jnp.where(mask, s, NEG_INF)
 
         m_cur = jnp.maximum(m_prev, jnp.max(s, axis=-1))
@@ -143,10 +145,13 @@ def flash_attention(
     if segmented:
         # Segment ids are per (batch, position): grid axis 0 runs over
         # b*h flattened programs, so the index map recovers the batch.
-        in_specs.append(pl.BlockSpec((None, block_q), lambda i, j: (i // h, j)))
-        in_specs.append(pl.BlockSpec((None, sk), lambda i, j: (i // h, 0)))
-        operands.append(q_segment_ids.astype(jnp.int32))
-        operands.append(kv_segment_ids.astype(jnp.int32))
+        # Query ids come as a (Sq, 1) column and key ids as a (1, Sk) row
+        # per batch row, so each block's last two dimensions are legal
+        # TPU tiles and the mask needs no in-kernel transpose.
+        in_specs.append(pl.BlockSpec((None, block_q, 1), lambda i, j: (i // h, j, 0)))
+        in_specs.append(pl.BlockSpec((None, 1, sk), lambda i, j: (i // h, 0, 0)))
+        operands.append(q_segment_ids.astype(jnp.int32)[:, :, None])
+        operands.append(kv_segment_ids.astype(jnp.int32)[:, None, :])
     out = pl.pallas_call(
         kernel,
         grid=(b * h, sq // block_q),
@@ -164,18 +169,20 @@ def flash_attention(
 
 
 def _paged_attn_kernel(
-    q_ref, k_ref, v_ref, *rest,
+    tbl_ref, pos_ref, slot_ref, q_ref, k_ref, v_ref, *rest,
     page_size, num_pages, num_blocks, window, sm_scale, softcap, quantized,
 ):
     if quantized:
-        ks_ref, vs_ref, tbl_ref, pos_ref, slot_ref, o_ref = rest
+        ks_ref, vs_ref, o_ref = rest
     else:
-        tbl_ref, pos_ref, slot_ref, o_ref = rest
-    q = q_ref[...].astype(jnp.float32) * sm_scale  # (d,)
-    pos = pos_ref[...]
-    slot = slot_ref[...]
+        (o_ref,) = rest
+    # scalar-prefetched (SMEM) addressing for this program's query token
+    t = pl.program_id(1)
+    pos = pos_ref[t]
+    slot = slot_ref[t]
     valid_q = slot >= 0
     slot_s = jnp.maximum(slot, 0)
+    q = q_ref[...].astype(jnp.float32) * sm_scale  # (G, D): the KV head's group
 
     # Admissible logical-block range for this query: its slot's blocks up
     # to (and including) its own position's block, lower-bounded by the
@@ -189,49 +196,49 @@ def _paged_attn_kernel(
 
     def body(bi, carry):
         acc, m_prev, l_prev = carry
-        page = tbl_ref[slot_s, bi]
+        page = tbl_ref[slot_s * num_blocks + bi]
         # unallocated sentinel (num_pages) AND hostile negatives: a bad
         # table entry may only redirect the read to a masked tile, never
         # wrap around into another slot's pages
         ok = (page >= 0) & (page < num_pages)
         page_s = jnp.clip(page, 0, num_pages - 1)
-        k_tile = pl.load(
-            k_ref, (pl.dslice(page_s * page_size, page_size), slice(None))
-        ).astype(jnp.float32)  # (page_size, d)
-        v_tile = pl.load(
-            v_ref, (pl.dslice(page_s * page_size, page_size), slice(None))
-        ).astype(jnp.float32)
+        start = pl.multiple_of(page_s * page_size, page_size)
+        k_tile = k_ref[pl.ds(start, page_size), :].astype(jnp.float32)  # (ps, d)
+        v_tile = v_ref[pl.ds(start, page_size), :].astype(jnp.float32)
+        s = jax.lax.dot_general(
+            q, k_tile, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )  # (G, ps)
         if quantized:
-            # int8 pages: dequantize per row inside the online-softmax
-            # loop (scales are per (page-row, kv-head), written at
-            # quantization time alongside the int8 rows).
-            ks = pl.load(ks_ref, (pl.dslice(page_s * page_size, page_size),))
-            vs = pl.load(vs_ref, (pl.dslice(page_s * page_size, page_size),))
-            k_tile = k_tile * ks[:, None]
-            v_tile = v_tile * vs[:, None]
-        s = jnp.dot(k_tile, q)  # (page_size,)
+            # int8 pages: per-row dequant scales (per (page-row, kv-head),
+            # written at quantization time alongside the int8 rows) applied
+            # to the scores and to the softmax weights — the same sums as
+            # scaling the K/V rows, without the (ps, d) multiplies
+            s = s * ks_ref[pl.ds(page_s, 1), :]
         if softcap > 0.0:
             s = softcap * jnp.tanh(s / softcap)
-        kpos = bi * page_size + jax.lax.iota(jnp.int32, page_size)
+        kpos = bi * page_size + jax.lax.broadcasted_iota(jnp.int32, (1, page_size), 1)
         mask = (kpos <= pos) & ok
         if window > 0:
             mask &= kpos > pos - window
         s = jnp.where(mask, s, NEG_INF)
-        m_cur = jnp.maximum(m_prev, jnp.max(s))
+        m_cur = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         alpha = jnp.exp(m_prev - m_cur)
         # mask p explicitly: when every position so far is masked, m_cur
         # is still NEG_INF and exp(s - m_cur) would be 1, not 0 — a
         # fully-masked query must come out all-zero
         p = jnp.where(mask, jnp.exp(s - m_cur), 0.0)
-        l_cur = l_prev * alpha + jnp.sum(p)
-        acc = acc * alpha + jnp.dot(p, v_tile)
+        l_cur = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        if quantized:
+            p = p * vs_ref[pl.ds(page_s, 1), :]
+        acc = acc * alpha + jnp.dot(p, v_tile, preferred_element_type=jnp.float32)
         return acc, m_cur, l_cur
 
-    d = q_ref.shape[-1]
-    acc0 = jnp.zeros((d,), jnp.float32)
-    acc, m, l = jax.lax.fori_loop(
-        lo, hi, body, (acc0, jnp.float32(NEG_INF), jnp.float32(0.0))
-    )
+    g, d = q.shape
+    acc0 = jnp.zeros((g, d), jnp.float32)
+    m0 = jnp.full((g, 1), NEG_INF, jnp.float32)
+    l0 = jnp.zeros((g, 1), jnp.float32)
+    acc, m, l = jax.lax.fori_loop(lo, hi, body, (acc0, m0, l0))
     o_ref[...] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
 
 
@@ -255,21 +262,32 @@ def paged_flash_attention(
     physical row is ``(tables[slot, position // page_size], position %
     page_size)``.  Instead of materializing each token's logical buffer
     (what the jnp path does), the kernel walks the query's *block table*:
-    one program per (token, head) runs the online-softmax loop over that
-    slot's admissible logical blocks only, loading each key tile by its
-    page id — the loop never reads another slot's pages, so cross-request
-    isolation is structural, not a mask (adversarially tested in
-    ``tests/test_kernels.py``).  Entries with ``tables[s, b] >=
-    num_pages`` (the unallocated sentinel) are mask-dropped; padding
-    queries (``q_slots < 0``) return zero rows.
+    one program per (KV head, token) runs the online-softmax loop for the
+    head's whole query group over that slot's admissible logical blocks
+    only, loading each key tile by its page id — the loop never reads
+    another slot's pages, so cross-request isolation is structural, not a
+    mask (adversarially tested in ``tests/test_kernels.py``).  Entries
+    with ``tables[s, b] >= num_pages`` (the unallocated sentinel) or
+    ``< 0`` are mask-dropped; padding queries (``q_slots < 0``) return
+    zero rows.
+
+    TPU blocking: the block tables, positions and slots are scalar
+    prefetch (SMEM), so each program reads its addressing as scalars;
+    queries are blocked ``(G, D)`` per (KV head, token) — whole last two
+    dimensions, as the TPU compiler requires.  The grid runs KV heads
+    outermost, so a head's pool block stays resident while every token
+    attends to it.
 
     Like the dense kernel above (whole-K block specs), each program
-    *stages* the full per-head pool as one Pallas block and prunes reads
-    inside it, which bounds the pool at VMEM size on real hardware
-    (~16 MiB: fine for the serving shapes this repo compiles, not for a
-    production multi-GiB pool).  Lifting that bound needs the
-    scalar-prefetch grid spec (``pltpu.PrefetchScalarGridSpec``) DMA-ing
-    pages by table entry — the known TPU follow-up.
+    *stages* the full per-head pool as one VMEM block and prunes reads
+    inside it, which bounds the pool by the compiler's scoped VMEM limit
+    (its default, not the chip's whole VMEM).  At internlm2-1.8b widths
+    (8 KV heads, head_dim 128, 16-token pages, 64 query tokens) the
+    largest pool that compiles for a v5e is 1,792 pages (28,672 tokens)
+    with bf16 pages and 3,184 pages (50,944 tokens) with int8 pages; 8
+    pages more and the compiler runs out of VMEM.  Lifting that bound
+    needs a kernel that DMAs pages by table entry from an HBM-resident
+    pool.
 
     The jnp oracle is ``repro.kernels.ref.paged_attention_ref``; the
     fused XLA path used off-TPU is ``paged_attention_xla`` below.
@@ -285,9 +303,11 @@ def paged_flash_attention(
         raise ValueError("pass both k_scale and v_scale, or neither")
 
     # (KV, num_pages * page_size, D): one flat row pool per KV head, so a
-    # page id turns into a dslice start inside the kernel.
-    kr = k_pool.transpose(2, 0, 1, 3).reshape(kvh, num_pages * page_size, d)
-    vr = v_pool.transpose(2, 0, 1, 3).reshape(kvh, num_pages * page_size, d)
+    # page id turns into a row-slice start inside the kernel.
+    rows = num_pages * page_size
+    kr = k_pool.transpose(2, 0, 1, 3).reshape(kvh, rows, d)
+    vr = v_pool.transpose(2, 0, 1, 3).reshape(kvh, rows, d)
+    qr = q.reshape(t, kvh, g, d)
 
     kernel = functools.partial(
         _paged_attn_kernel,
@@ -295,39 +315,39 @@ def paged_flash_attention(
         window=window, sm_scale=1.0 / math.sqrt(d), softcap=softcap,
         quantized=quantized,
     )
+    # index maps take (grid indices..., *scalar prefetch refs)
     in_specs = [
-        pl.BlockSpec((None, None, d), lambda i, j: (i, j, 0)),  # q token/head
-        pl.BlockSpec((None, num_pages * page_size, d), lambda i, j, g=g: (j // g, 0, 0)),
-        pl.BlockSpec((None, num_pages * page_size, d), lambda i, j, g=g: (j // g, 0, 0)),
+        pl.BlockSpec((None, None, g, d), lambda j, i, *_: (i, j, 0, 0)),
+        pl.BlockSpec((None, rows, d), lambda j, i, *_: (j, 0, 0)),
+        pl.BlockSpec((None, rows, d), lambda j, i, *_: (j, 0, 0)),
     ]
-    operands = [q, kr, vr]
+    operands = [qr, kr, vr]
     if quantized:
-        # Per-row dequant scales, flattened alongside their pools.
-        ksr = k_scale.transpose(2, 0, 1).reshape(kvh, num_pages * page_size)
-        vsr = v_scale.transpose(2, 0, 1).reshape(kvh, num_pages * page_size)
-        in_specs.append(pl.BlockSpec((None, num_pages * page_size), lambda i, j, g=g: (j // g, 0)))
-        in_specs.append(pl.BlockSpec((None, num_pages * page_size), lambda i, j, g=g: (j // g, 0)))
-        operands.append(ksr.astype(jnp.float32))
-        operands.append(vsr.astype(jnp.float32))
-    in_specs += [
-        pl.BlockSpec((num_slots, num_blocks), lambda i, j: (0, 0)),
-        pl.BlockSpec((None,), lambda i, j: (i,)),
-        pl.BlockSpec((None,), lambda i, j: (i,)),
-    ]
+        # Per-row dequant scales, one (num_pages, page_size) plane per KV
+        # head: a page id is a row of it.
+        for sc in (k_scale, v_scale):
+            in_specs.append(
+                pl.BlockSpec((None, num_pages, page_size), lambda j, i, *_: (j, 0, 0))
+            )
+            operands.append(sc.transpose(2, 0, 1).astype(jnp.float32))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(kvh, t),
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec((None, None, g, d), lambda j, i, *_: (i, j, 0, 0)),
+    )
     out = pl.pallas_call(
         kernel,
-        grid=(t, h),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((None, None, d), lambda i, j: (i, j, 0)),
-        out_shape=jax.ShapeDtypeStruct((t, h, d), q.dtype),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((t, kvh, g, d), q.dtype),
         interpret=interpret,
     )(
-        *operands,
-        tables.astype(jnp.int32),
+        tables.astype(jnp.int32).reshape(num_slots * num_blocks),
         q_pos.astype(jnp.int32),
         q_slots.astype(jnp.int32),
+        *operands,
     )
-    return out
+    return out.reshape(t, h, d)
 
 
 def paged_attention_xla(

@@ -147,8 +147,6 @@ def lower_combo(
 
     mem = compiled.memory_analysis()
     cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):  # jax 0.4 returns [dict]
-        cost = cost[0] if cost else {}
     hlo = compiled.as_text()
     coll = parse_collectives(hlo)
     walked = hlo_cost.analyze(hlo)  # trip-count-aware (scans multiplied)

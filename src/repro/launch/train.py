@@ -20,6 +20,7 @@ from repro.configs import ARCHITECTURES, PAPER_MODELS, get_config, get_smoke_con
 from repro.core import DropConfig, LatencyModel, NoiseModel
 from repro.data import DataConfig
 from repro.dist import Distribution
+from repro.launch import compile_cache
 from repro.train import TrainConfig, train
 from repro.train.resilience import SCENARIOS, make_scenario
 
@@ -65,6 +66,7 @@ def main():
                          "'pod,data,model' (e.g. 2,16,16); empty = "
                          "single-device virtual-worker path")
     args = ap.parse_args()
+    compile_cache.enable()
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     dist = Distribution.from_spec(args.mesh) if args.mesh else None

@@ -342,10 +342,10 @@ def prefill_chunk(
     dropped past expert capacity this step (0 unless
     ``moe_impl="capacity"``).
 
-    Host-side driver loops must synchronize each step (e.g.
+    Host-side driver loops synchronize each step (e.g.
     ``jax.block_until_ready`` or materializing the sampled token) before
-    reusing the host-side token/position buffers: with async dispatch,
-    jax<=0.4 CPU can read freed host memory mid-execution otherwise.
+    reusing the host-side token/position buffers: with async dispatch a
+    CPU backend was seen reading them after they were rebound.
     ``ContinuousBatcher`` does this for you.
 
     ``cache`` is the legacy dict from ``init_decode_cache`` or a

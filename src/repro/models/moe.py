@@ -252,10 +252,6 @@ def apply_moe_spmd(p, x: jnp.ndarray, cfg: ModelConfig, mesh) -> Tuple[jnp.ndarr
         mixtral's 8 experts on 16-way TP.
     """
     from jax.sharding import PartitionSpec as P
-    try:
-        from jax.experimental.shard_map import shard_map
-    except ImportError:  # newer jax
-        from jax import shard_map  # type: ignore
 
     dp = tuple(a for a in ("pod", "data") if a in mesh.axis_names)
     tp = "model" if "model" in mesh.axis_names else None
@@ -298,12 +294,12 @@ def apply_moe_spmd(p, x: jnp.ndarray, cfg: ModelConfig, mesh) -> Tuple[jnp.ndarr
             aux = jax.lax.pmean(aux, dp)
         return y.reshape(b, s, d), aux
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local_fn,
         mesh=mesh,
         in_specs=({k: w_specs[k] for k in p}, P(dp_spec, None, tp)),
         out_specs=(P(dp_spec, None, tp), P()),
-        check_rep=False,
+        check_vma=False,
     )
     return fn({k: p[k] for k in p}, x)
 
@@ -396,18 +392,14 @@ def apply_moe(p, x, cfg: ModelConfig, impl: str = "sort", mesh=None):
     if impl == "spmd":
         if mesh is None:
             mesh = _current_mesh()
-        if mesh is not None and mesh.devices.size > 1:
+        if mesh is not None and mesh.size > 1:
             return apply_moe_spmd(p, x, cfg, mesh)
         return apply_moe_sort(p, x, cfg)
     return apply_moe_sort(p, x, cfg)
 
 
 def _current_mesh():
-    """The mesh from the enclosing ``with mesh:`` context, if any."""
-    try:
-        from jax._src import mesh as mesh_lib
-
-        m = mesh_lib.thread_resources.env.physical_mesh
-        return None if m.empty else m
-    except Exception:
-        return None
+    """The mesh set by an enclosing ``jax.set_mesh`` (as an
+    ``AbstractMesh`` while tracing), or None outside one."""
+    m = jax.sharding.get_abstract_mesh()
+    return None if m.empty else m

@@ -44,7 +44,7 @@ def constrain_activations(x: jnp.ndarray, cfg: ModelConfig) -> jnp.ndarray:
     from .moe import _current_mesh  # lazy: avoids cycle
 
     mesh = _current_mesh()
-    if mesh is None or mesh.devices.size == 1 or x.ndim != 3:
+    if mesh is None or mesh.size == 1 or x.ndim != 3:
         return x
     from jax.sharding import NamedSharding, PartitionSpec as P
 

@@ -778,10 +778,12 @@ class ContinuousBatcher:
         )
         # Synchronize every step (np.asarray blocks on the result; the
         # jitted sampler dispatches asynchronously in the same chain, so
-        # sampling adds no extra sync).  The sync itself is load-bearing:
-        # with async dispatch, rebinding the host token/pos buffers while
-        # the step is still in flight corrupts the computation on
-        # jax<=0.4 CPU (observed use-after-free garbage).
+        # sampling adds no extra sync).  The host needs the sampled tokens
+        # to schedule the next step, and the sync keeps the host token/pos
+        # buffers alive until the step that reads them has run: async
+        # dispatch once read them after they were rebound (garbage tokens
+        # on a CPU backend).  Whether a TPU step needs the wait before the
+        # next layout is built is open (ROADMAP 1.2).
         next_tok = np.asarray(sample_tokens(
             logits, seeds, oidx, temps, topk, topp
         ))  # (B, C)

@@ -97,6 +97,9 @@ class TrainResult:
     # the full trajectory, not just the final scalar.
     tau_trajectory: List[Tuple[int, float]] = dataclasses.field(default_factory=list)
     telemetry: Optional[Dict[str, Any]] = None  # ComputeTelemetry.summary()
+    # host-clock seconds around each jitted step, up to block_until_ready
+    # (the first includes its compilation)
+    host_step_s: List[float] = dataclasses.field(default_factory=list)
 
     @property
     def cum_time(self) -> np.ndarray:
@@ -256,7 +259,7 @@ def train(
             extra={"resilience": res_state},
         )
 
-    losses, sim_times, drops = [], [], []
+    losses, sim_times, drops, host_times = [], [], [], []
     for step in range(start_step, tcfg.steps):
         if dist is None:
             mbs = microbatches_at(step, data_cfg, total_m)
@@ -335,6 +338,7 @@ def train(
         losses.append(float(loss))
         sim_times.append(t_iter)
         drops.append(drop_frac)
+        host_times.append(host_step_s)
 
         telemetry.record(
             step, t, host_step_s=host_step_s, tau=tau, drop_fraction=drop_frac
@@ -356,4 +360,5 @@ def train(
     return TrainResult(
         params, losses, sim_times, drops, float(tau), metrics,
         tau_trajectory=final_trajectory, telemetry=telemetry.summary(),
+        host_step_s=host_times,
     )

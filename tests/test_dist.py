@@ -3,9 +3,9 @@
 Multi-device tests run in a subprocess so the 8 fake host devices never
 leak into the rest of the suite (smoke tests must see 1 device).
 
-Meshes are built through ``repro.dist.mesh.make_mesh``, the jax-0.4/0.5
-compat helper, so this module *executes* on jax 0.4.x (no
-``jax.sharding.AxisType``) instead of skipping forever.
+Meshes are built through ``repro.dist.mesh.make_mesh`` (Auto axis types,
+as every mesh of the program is) and set with ``jax.set_mesh``, as
+``repro.dist.StepBundle`` sets them, so the model code sees the mesh.
 """
 import importlib.util
 import json
@@ -81,7 +81,7 @@ class TestMultiDevice:
             p = init_moe(jax.random.PRNGKey(0), cfg)
             x = jax.random.normal(jax.random.PRNGKey(1), (8, 16, 32))
             yd, _ = apply_moe_dense(p, x, cfg)
-            with mesh:
+            with jax.set_mesh(mesh):
                 ys, _ = jax.jit(lambda p, x: apply_moe_spmd(p, x, cfg, mesh))(p, x)
             print("ERR", float(jnp.abs(ys - yd).max()))
         """)
@@ -101,7 +101,7 @@ class TestMultiDevice:
             p = init_moe(jax.random.PRNGKey(0), cfg)
             x = jax.random.normal(jax.random.PRNGKey(1), (8, 16, 64))
             yd, _ = apply_moe_dense(p, x, cfg)
-            with mesh:
+            with jax.set_mesh(mesh):
                 ys, _ = jax.jit(lambda p, x: apply_moe_spmd(p, x, cfg, mesh))(p, x)
             print("ERR", float(jnp.abs(ys - yd).max()))
         """)
@@ -132,7 +132,7 @@ class TestMultiDevice:
 
             opt, step = S.make_train_step(cfg, shape, drop, n_workers=4, lr=1e-2)
             o0 = opt.init(params)
-            with mesh:
+            with jax.set_mesh(mesh):
                 p_sh = param_shardings(jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg)), mesh)
                 o_sh = opt_shardings(jax.eval_shape(opt.init, params), mesh)
                 f = jax.jit(step, in_shardings=(p_sh, o_sh, None, None),
@@ -174,7 +174,7 @@ class TestMultiDevice:
             oa = jax.eval_shape(opt.init, pa)
             sds = jax.ShapeDtypeStruct
             batch = {'tokens': sds((16, 16), jnp.int32), 'weights': sds((16, 16), jnp.float32)}
-            with mesh:
+            with jax.set_mesh(mesh):
                 p_sh = param_shardings(pa, mesh)
                 o_sh = opt_shardings(oa, mesh)
                 from repro.dist.sharding import batch_spec

@@ -11,18 +11,12 @@ def walked(fn, *args):
     return analyze(c.as_text()), c
 
 
-def xla_cost(c):
-    """compiled.cost_analysis() returns a dict on jax>=0.5, [dict] before."""
-    ca = c.cost_analysis()
-    return ca[0] if isinstance(ca, (list, tuple)) else ca
-
-
 class TestWalker:
     def test_matmul_exact(self):
         a, b = jnp.ones((256, 512)), jnp.ones((512, 128))
         w, c = walked(lambda a, b: a @ b, a, b)
         assert w["flops"] == 2 * 256 * 512 * 128
-        assert w["flops"] == xla_cost(c)["flops"]
+        assert w["flops"] == c.cost_analysis()["flops"]
 
     def test_scan_multiplies_body(self):
         a = jnp.ones((128, 128))
@@ -37,7 +31,7 @@ class TestWalker:
         dots = 10 * 2 * 128**3
         assert w["flops"] == pytest.approx(dots, rel=0.02)
         # XLA's own count misses the trip count
-        assert xla_cost(c)["flops"] < w["flops"]
+        assert c.cost_analysis()["flops"] < w["flops"]
         assert w["unknown_trip_loops"] == 0
 
     def test_nested_scan(self):

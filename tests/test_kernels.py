@@ -1,4 +1,10 @@
-"""Pallas kernel validation: interpret-mode sweeps vs pure-jnp oracles."""
+"""Pallas kernel validation: interpret-mode sweeps vs pure-jnp oracles.
+
+The kernels on the serving path (``paged_flash_attention``, bf16 and int8
+pools, hostile block tables; ``ssd_segment``) keep a few fast cases in
+tier-1, so an API break in Pallas shows on every run; the wide sweeps
+are in the slow lane.
+"""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -6,14 +12,13 @@ import pytest
 
 from repro.kernels import ops, ref
 
-pytestmark = pytest.mark.slow  # full-lane only; tier-1 covers this path via faster tests
-
 
 def rand(shape, dtype, seed):
     x = jax.random.normal(jax.random.PRNGKey(seed), shape, jnp.float32)
     return x.astype(dtype)
 
 
+@pytest.mark.slow
 class TestFlashAttention:
     @pytest.mark.parametrize("b,h,kv,s,d", [
         (1, 4, 4, 128, 64),   # MHA
@@ -59,6 +64,7 @@ class TestFlashAttention:
         np.testing.assert_allclose(np.asarray(o1), np.asarray(o2), atol=2e-5)
 
 
+@pytest.mark.slow
 class TestFlashAttentionSegments:
     """Segment-id masking — the packed-serving mask term (one flattened
     sequence holding several requests; queries must stay inside their own
@@ -176,6 +182,38 @@ class TestPagedAttention:
         expect = ref.paged_attention_ref(q, kp, vp, tbl, pos, slots, window=window)
         np.testing.assert_allclose(np.asarray(out), np.asarray(expect), atol=2e-5)
 
+    def test_bf16_pool_matches_ref(self):
+        """bf16 queries and pools (the serving dtype): f32 accumulation
+        inside the kernel keeps it within bf16 rounding of the oracle."""
+        q, kp, vp, tbl, pos, slots, _, _ = self.scenario(seed=5)
+        q, kp, vp = (x.astype(jnp.bfloat16) for x in (q, kp, vp))
+        out = ops.paged_flash_attention(q, kp, vp, tbl, pos, slots, interpret=True)
+        expect = ref.paged_attention_ref(q, kp, vp, tbl, pos, slots)
+        assert out.dtype == jnp.bfloat16
+        np.testing.assert_allclose(
+            np.asarray(out, np.float32), np.asarray(expect, np.float32), atol=2e-2
+        )
+
+    def test_hostile_table_entries_masked(self):
+        """Negative and past-the-pool entries in a live slot's table are
+        masked exactly like the unallocated sentinel: the slot attends only
+        to its real pages, and a slot whose every entry is hostile comes
+        out all-zero."""
+        q, kp, vp, tbl, pos, slots, _, _ = self.scenario(seed=6)
+        num_pages = kp.shape[0]
+        hostile = np.asarray(tbl).copy()
+        hostile[0, 1] = -1  # a hole in the middle of slot 0's history
+        hostile[1, :] = [-7, num_pages + 3, -1, num_pages]  # nothing real
+        hostile = jnp.asarray(hostile)
+        out = np.asarray(ops.paged_flash_attention(
+            q, kp, vp, hostile, pos, slots, interpret=True))
+        expect = np.asarray(ref.paged_attention_ref(q, kp, vp, hostile, pos, slots))
+        np.testing.assert_allclose(out, expect, atol=2e-5)
+        np.testing.assert_array_equal(out[1], np.zeros_like(out[1]))
+        clean = np.asarray(ops.paged_flash_attention(
+            q, kp, vp, tbl, pos, slots, interpret=True))
+        assert np.abs(out[0] - clean[0]).max() > 1e-3  # the hole was dropped
+
     def test_matches_contiguous_oracle(self):
         """Each slot's paged output must equal dense attention over that
         slot's logically-contiguous KV alone."""
@@ -267,6 +305,7 @@ class TestPagedAttention:
         codes = np.clip(np.round(pool / scale[..., None]), -127, 127)
         return jnp.asarray(codes.astype(np.int8)), jnp.asarray(scale)
 
+    @pytest.mark.slow
     @pytest.mark.parametrize("g", [1, 2, 4])
     @pytest.mark.parametrize("page_size", [4, 16])
     @pytest.mark.parametrize("window", [0, 7])
@@ -340,6 +379,49 @@ class TestPagedAttention:
         assert np.abs(out[sel]).max() < 1e3, "foreign int8 pages leaked"
 
 
+class TestSsdSegment:
+    """Segment-masked SSD term (the packed serving path of 'M' layers)."""
+
+    @staticmethod
+    def packed_inputs(t=48, h=3, p=16, n=8, seed=0):
+        """Three contiguous requests (slots 2, 0, 1) then padding (-1),
+        with dt zeroed on padding as the model's packed branch does."""
+        rng = np.random.default_rng(seed)
+        seg = np.full(t, -1, np.int32)
+        seg[0:20], seg[20:31], seg[31:44] = 2, 0, 1
+        dt = rng.uniform(0.1, 0.9, (t, h)).astype(np.float32)
+        dt[seg < 0] = 0.0
+        a = rng.uniform(0.1, 1.0, (h,)).astype(np.float32)
+        cum = np.cumsum(dt * a, axis=0)
+        x = rng.normal(size=(t, h, p)).astype(np.float32)
+        b = rng.normal(size=(t, n)).astype(np.float32)
+        c = rng.normal(size=(t, n)).astype(np.float32)
+        return tuple(jnp.asarray(v) for v in (x, dt, cum, b, c, seg))
+
+    def test_matches_ref(self):
+        x, dt, cum, b, c, seg = self.packed_inputs()
+        out = ops.ssd_segment(x, dt, cum, b, c, seg, interpret=True)
+        expect = ref.ssd_segment_ref(x, dt, cum, b, c, seg)
+        np.testing.assert_allclose(
+            np.asarray(out), np.asarray(expect), atol=1e-4, rtol=1e-4
+        )
+
+    def test_segments_isolated_and_padding_zero(self):
+        """Poisoning one request's inputs moves only that request's rows;
+        padding rows are exactly zero."""
+        x, dt, cum, b, c, seg = self.packed_inputs(seed=1)
+        clean = np.asarray(ops.ssd_segment(x, dt, cum, b, c, seg, interpret=True))
+        first = np.asarray(seg) == 2
+        x_bad = x.at[jnp.asarray(first)].add(1e4)
+        out = np.asarray(ops.ssd_segment(x_bad, dt, cum, b, c, seg, interpret=True))
+        others = np.asarray(seg) != 2
+        np.testing.assert_array_equal(out[others], clean[others])
+        assert np.abs(out[first]).max() > 1e3
+        pad = np.asarray(seg) < 0
+        np.testing.assert_array_equal(out[pad], np.zeros_like(out[pad]))
+
+
+@pytest.mark.slow
 class TestRmsnorm:
     @pytest.mark.parametrize("shape", [(4, 128), (3, 17, 256), (1, 1, 1024), (513, 128)])
     @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
@@ -354,6 +436,7 @@ class TestRmsnorm:
         )
 
 
+@pytest.mark.slow
 class TestMaskedAccum:
     @pytest.mark.parametrize("n", [128, 1000, 65536 + 3])
     @pytest.mark.parametrize("gdtype", [jnp.float32, jnp.bfloat16])

@@ -313,3 +313,4 @@ class TestTrainerResilience:
             step0 = r.tau_trajectory[1][0]
             assert np.isfinite(taus[step0:]).all()
         assert r.telemetry is not None and r.telemetry["steps"] == 30
+        assert len(r.host_step_s) == 30 and min(r.host_step_s) > 0

@@ -50,7 +50,7 @@ class TestSsdChunkKernel:
         b = jax.random.normal(ks[3], (bs, l, n), jnp.float32)
         c = jax.random.normal(jax.random.PRNGKey(2), (bs, l, n), jnp.float32)
 
-        y_model = _ssd_chunked(x, dt, a, b, c, chunk=l)  # single chunk
+        y_model, _ = _ssd_chunked(x, dt, a, b, c, chunk=l)  # single chunk
         cum = jnp.cumsum(dt * a, axis=1)  # (B, L, H)
         y_kernel = ops.ssd_chunk(
             x[:, None], dt[:, None], cum[:, None], b[:, None], c[:, None],
