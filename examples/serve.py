@@ -174,6 +174,9 @@ def main():
     print(f"  max step tokens {s['max_step_tokens']:.0f}  "
           f"deferred {s['deferred_tokens']:.0f}  "
           f"max step wall {s['max_step_wall']*1e3:.1f} ms")
+    print(f"  mean step {s['mean_step_wall']*1e3:.2f} ms: host "
+          f"{(s['mean_step_wall'] - s['mean_step_sync'])*1e3:.2f} ms, waiting on "
+          f"the device {s['mean_step_sync']*1e3:.2f} ms")
     if eng.kv is not None:
         print(f"  paged KV: {s['peak_used_pages']:.0f}/{s['num_pages']:.0f} "
               f"peak pages used ({args.page_size} tokens each), "

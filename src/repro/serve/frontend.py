@@ -47,7 +47,8 @@ from collections import deque
 from typing import AsyncIterator, Deque, Dict, List, Optional, Sequence
 
 from .sampling import SamplingParams
-from .scheduler import AdmissionError, ContinuousBatcher, Request, StepStats
+from .scheduler import AdmissionError, ContinuousBatcher, Request
+from .spans import span
 
 #: stream terminator pushed into a RequestStream's token queue
 _END = object()
@@ -199,9 +200,7 @@ class AsyncEngine:
         self._wake: Optional[asyncio.Event] = None
         self._stopping = False
         self._abort = False
-        self.step_log: List[StepStats] = []  # appended by the engine callback
         self.counters = {"submitted": 0, FINISHED: 0, DROPPED: 0, CANCELLED: 0}
-        engine.add_step_callback(self.step_log.append)
 
     @property
     def engine(self) -> ContinuousBatcher:
@@ -296,31 +295,41 @@ class AsyncEngine:
     # -- driver -------------------------------------------------------------
 
     async def _drive(self) -> None:
+        """The step loop.  Its host work between engine steps is spanned
+        (``frontend:<phase>``, ``serve.spans``) on the profiler's clock,
+        the executor hand-off around each step included, so every gap
+        between two engine steps falls inside a span."""
         loop = asyncio.get_running_loop()
         try:
             while True:
                 self._wake.clear()
-                self._apply_cancels()
-                self._feed()
-                self._expire(time.perf_counter())
-                if self._abort:
-                    self._shed_all()
+                with span("frontend:cancels"):
+                    self._apply_cancels()
+                with span("frontend:feed"):
+                    self._feed()
+                with span("frontend:expire"):
+                    self._expire(time.perf_counter())
+                    if self._abort:
+                        self._shed_all()
                 if self._engine.busy:
                     # the blocking model step runs off-loop; arrivals and
                     # cancellations land in host structures meanwhile and
                     # are applied at the top of the next iteration
-                    await loop.run_in_executor(None, self._engine.step)
-                    self._publish()
+                    with span("frontend:handoff"):
+                        await loop.run_in_executor(None, self._engine.step)
+                    with span("frontend:publish"):
+                        self._publish()
                 elif self._stopping:
                     break
                 else:
                     # idle (or gated on queue_timeout): sleep until a
                     # submission/cancel/stop, re-checking expiries
                     # periodically
-                    try:
-                        await asyncio.wait_for(self._wake.wait(), timeout=0.05)
-                    except asyncio.TimeoutError:
-                        pass
+                    with span("frontend:idle"):
+                        try:
+                            await asyncio.wait_for(self._wake.wait(), timeout=0.05)
+                        except asyncio.TimeoutError:
+                            pass
         except Exception:
             # a driver crash must not strand clients on silent streams:
             # end every in-flight stream (the engine's state is suspect,

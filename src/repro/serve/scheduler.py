@@ -74,6 +74,7 @@ from ..models.model import (
 from . import packing
 from .kv import KVCache, KVCacheSpec, reset_recurrent_state
 from .sampling import SamplingParams, sample_tokens
+from .spans import span
 from .spec import Proposer, SpecConfig, accept_sampled
 
 PyTree = object
@@ -206,7 +207,24 @@ class StepStats:
     decode_tokens: int  # decode slots fed (1 baseline token each)
     prefill_tokens: int  # prompt tokens consumed this step
     deferred_tokens: int  # prompt tokens pushed past the deadline
-    wall_time: float  # host-measured step duration (seconds)
+    #: host-measured step duration (seconds): the ``engine:step`` span,
+    #: which the ``phases`` cover
+    wall_time: float
+    #: ``time.perf_counter()`` at the start of the step
+    started_at: float = 0.0
+    #: host seconds of each phase of the step (``serve.spans``): admit,
+    #: share, propose, schedule, kv_prepare, pack, dispatch (the jitted
+    #: step and sampler, which return asynchronously), sync (the wait
+    #: for the sampled tokens), sync_overflow, emit
+    phases: Dict[str, float] = dataclasses.field(default_factory=dict)
+    #: "decode" when every grant is one token (the packed engine then
+    #: runs its decode-capacity program), else "mixed"
+    kind: str = "mixed"
+    #: why admission stopped while a request was still queued: "slots"
+    #: (no free slot), "pool" (the page pool cannot reserve the head
+    #: request's worst case) or "prefix" (the head is parked behind an
+    #: in-flight prefix); None when nothing was left waiting
+    admit_blocked: Optional[str] = None
     shared_tokens: int = 0  # prompt tokens covered by prefix-cache pages
     used_pages: int = 0  # paged layout: pages referenced after this step
     draft_tokens: int = 0  # speculative draft tokens verified this step
@@ -232,6 +250,12 @@ class StepStats:
     @property
     def scheduled_tokens(self) -> int:
         return self.decode_tokens + self.draft_tokens + self.prefill_tokens
+
+    @property
+    def sync_time(self) -> float:
+        """Seconds the host waited on the device for this step's
+        results; ``wall_time`` less this is the host's own work."""
+        return self.phases.get("sync", 0.0) + self.phases.get("sync_overflow", 0.0)
 
 
 @dataclasses.dataclass
@@ -455,6 +479,7 @@ class ContinuousBatcher:
         self.step_stats: List[StepStats] = []
         self._shared_step = 0
         self._overflow_step = 0
+        self._phases: Dict[str, float] = {}  # the running step's phases
         self._step_callbacks: List = []
 
     # ------------------------------------------------------------------
@@ -595,22 +620,27 @@ class ContinuousBatcher:
             return False
         return best * ps > self.kv.probe_shared(head.prompt)
 
-    def _admit(self):
+    def _admit(self) -> Optional[str]:
+        """Admit queued requests into free slots, oldest first.  Returns
+        why admission stopped with a request still queued ("slots",
+        "pool" or "prefix"; see ``StepStats.admit_blocked``), or None."""
         for i, s in enumerate(self.slots):
-            if s.free and self.queue:
+            if not self.queue:
+                return None
+            if s.free:
                 if self.kv is not None:
                     head = self.queue[0]
                     if self._dedup_inflight_prefix(head):
                         # park: the leader's prefix pages will cover this
                         # prompt; admission stays FIFO (no skip-ahead)
-                        break
+                        return "prefix"
                     shared = self.kv.admit_slot(
                         i, head.prompt, head.max_new_tokens
                     )
                     if shared is None:
                         # the pool cannot guarantee the head request yet;
                         # admission stays FIFO (no skip-ahead starvation)
-                        break
+                        return "pool"
                 else:
                     shared = 0
                     if self.recurrent:
@@ -628,6 +658,7 @@ class ContinuousBatcher:
                 self._shared_step += shared
                 s.req.admitted_step = self.steps
                 s.req.admitted_at = time.perf_counter()
+        return "slots" if self.queue else None
 
     @property
     def busy(self) -> bool:
@@ -746,36 +777,40 @@ class ContinuousBatcher:
         column's prediction (negative mid-prefill; those columns' samples
         are discarded, so their key indices are clamped at 0).
         """
-        b = len(self.slots)
-        mixed = any(self.slots[i].prefilling for i, _, _ in grants)
-        c = self.chunk_size if mixed else 1
-        if self.spec is not None:
-            # verify grants are up to 1 + k wide; keep the two-programs
-            # shape story by folding them into fixed widths
-            c = max(c, self.spec.k + 1) if mixed else self.spec.k + 1
-        tokens = np.zeros((b, c), np.int32)
-        pos = np.zeros((b,), np.int32)
-        lens = np.zeros((b,), np.int32)
-        seeds = np.zeros((b, c), np.uint32)
-        oidx = np.zeros((b, c), np.int32)
-        temps = np.zeros((b, c), np.float32)  # unused rows: argmax, discarded
-        topk = np.zeros((b, c), np.int32)
-        topp = np.ones((b, c), np.float32)
-        for i, pos0, toks in grants:
-            n = len(toks)
-            tokens[i, :n] = toks
-            pos[i] = pos0
-            lens[i] = n
-            sp = self.slots[i].req.sampling
-            seeds[i] = sp.seed & 0xFFFFFFFF
-            temps[i] = sp.temperature
-            topk[i] = sp.top_k
-            topp[i] = sp.top_p
-            oidx[i, :n] = np.maximum(out_base[i] + np.arange(n), 0)
-        logits, self.cache, aux = _engine_step(
-            self.params, self.cfg, self.cache, jnp.asarray(tokens),
-            jnp.asarray(pos), jnp.asarray(lens), moe_impl=self.moe_impl,
-        )
+        ph = self._phases
+        with span("engine:pack", ph):
+            b = len(self.slots)
+            mixed = any(self.slots[i].prefilling for i, _, _ in grants)
+            c = self.chunk_size if mixed else 1
+            if self.spec is not None:
+                # verify grants are up to 1 + k wide; keep the two-programs
+                # shape story by folding them into fixed widths
+                c = max(c, self.spec.k + 1) if mixed else self.spec.k + 1
+            tokens = np.zeros((b, c), np.int32)
+            pos = np.zeros((b,), np.int32)
+            lens = np.zeros((b,), np.int32)
+            seeds = np.zeros((b, c), np.uint32)
+            oidx = np.zeros((b, c), np.int32)
+            temps = np.zeros((b, c), np.float32)  # unused rows: argmax, discarded
+            topk = np.zeros((b, c), np.int32)
+            topp = np.ones((b, c), np.float32)
+            for i, pos0, toks in grants:
+                n = len(toks)
+                tokens[i, :n] = toks
+                pos[i] = pos0
+                lens[i] = n
+                sp = self.slots[i].req.sampling
+                seeds[i] = sp.seed & 0xFFFFFFFF
+                temps[i] = sp.temperature
+                topk[i] = sp.top_k
+                topp[i] = sp.top_p
+                oidx[i, :n] = np.maximum(out_base[i] + np.arange(n), 0)
+        with span("engine:dispatch", ph):
+            logits, self.cache, aux = _engine_step(
+                self.params, self.cfg, self.cache, jnp.asarray(tokens),
+                jnp.asarray(pos), jnp.asarray(lens), moe_impl=self.moe_impl,
+            )
+            sampled = sample_tokens(logits, seeds, oidx, temps, topk, topp)
         # Synchronize every step (np.asarray blocks on the result; the
         # jitted sampler dispatches asynchronously in the same chain, so
         # sampling adds no extra sync).  The host needs the sampled tokens
@@ -784,10 +819,13 @@ class ContinuousBatcher:
         # dispatch once read them after they were rebound (garbage tokens
         # on a CPU backend).  Whether a TPU step needs the wait before the
         # next layout is built is open (ROADMAP 1.2).
-        next_tok = np.asarray(sample_tokens(
-            logits, seeds, oidx, temps, topk, topp
-        ))  # (B, C)
-        self._overflow_step = int(np.asarray(aux["expert_overflow"]))
+        with span("engine:sync", ph):
+            next_tok = np.asarray(sampled)  # (B, C)
+        with span("engine:sync_overflow", ph):
+            self._overflow_step = int(np.asarray(aux["expert_overflow"]))
+            # free the step's device buffers here, not on return, so
+            # the phases cover the step
+            del logits, sampled, aux
         return {i: next_tok[i, : len(toks)] for i, _, toks in grants}
 
     def _run_packed(self, grants, out_base) -> Dict[int, np.ndarray]:
@@ -800,50 +838,54 @@ class ContinuousBatcher:
         (``PackedLayout.out_idx``), so a packed row samples exactly what
         the dense row for the same (request, output index) samples.
         """
-        capacity = self.packed_capacity
-        if all(len(toks) == 1 for _, _, toks in grants):
-            capacity = self.packed_decode_capacity
-        layout = packing.pack_step(grants, capacity, out_base=out_base)
-        seeds = np.zeros((capacity,), np.uint32)
-        temps = np.zeros((capacity,), np.float32)  # padding: argmax, discarded
-        topk = np.zeros((capacity,), np.int32)
-        topp = np.ones((capacity,), np.float32)
-        for i, (j, m) in layout.spans.items():
-            sp = self.slots[i].req.sampling
-            seeds[j : j + m] = sp.seed & 0xFFFFFFFF
-            temps[j : j + m] = sp.temperature
-            topk[j : j + m] = sp.top_k
-            topp[j : j + m] = sp.top_p
-        logits, self.cache, aux = _packed_engine_step(
-            self.params, self.cfg, self.cache, jnp.asarray(layout.tokens),
-            jnp.asarray(layout.slot_ids), jnp.asarray(layout.positions),
-            moe_impl=self.moe_impl,
-        )
-        next_tok = np.asarray(sample_tokens(
-            logits, seeds, layout.out_idx, temps, topk, topp
-        ))  # (P,) — syncs
-        self._overflow_step = int(np.asarray(aux["expert_overflow"]))
+        ph = self._phases
+        with span("engine:pack", ph):
+            capacity = self.packed_capacity
+            if all(len(toks) == 1 for _, _, toks in grants):
+                capacity = self.packed_decode_capacity
+            layout = packing.pack_step(grants, capacity, out_base=out_base)
+            seeds = np.zeros((capacity,), np.uint32)
+            temps = np.zeros((capacity,), np.float32)  # padding: argmax, discarded
+            topk = np.zeros((capacity,), np.int32)
+            topp = np.ones((capacity,), np.float32)
+            for i, (j, m) in layout.spans.items():
+                sp = self.slots[i].req.sampling
+                seeds[j : j + m] = sp.seed & 0xFFFFFFFF
+                temps[j : j + m] = sp.temperature
+                topk[j : j + m] = sp.top_k
+                topp[j : j + m] = sp.top_p
+        with span("engine:dispatch", ph):
+            logits, self.cache, aux = _packed_engine_step(
+                self.params, self.cfg, self.cache, jnp.asarray(layout.tokens),
+                jnp.asarray(layout.slot_ids), jnp.asarray(layout.positions),
+                moe_impl=self.moe_impl,
+            )
+            sampled = sample_tokens(logits, seeds, layout.out_idx, temps, topk, topp)
+        with span("engine:sync", ph):
+            next_tok = np.asarray(sampled)  # (P,)
+        with span("engine:sync_overflow", ph):
+            self._overflow_step = int(np.asarray(aux["expert_overflow"]))
+            # free the step's device buffers here, not on return, so
+            # the phases cover the step
+            del logits, sampled, aux
         return {i: next_tok[j : j + m] for i, (j, m) in layout.spans.items()}
 
-    def step(self):
-        """One engine iteration: mixed chunked-prefill + decode/verify."""
-        t0 = time.perf_counter()
-        queued0 = len(self.queue)  # queue depth before this step's admission
-        self._shared_step = 0
-        self._overflow_step = 0  # set by the step runner from the jit aux
-        self._admit()
-        if self.kv is not None:
-            # lazy prefix sharing: an older request may have finished
-            # writing pages this prompt can map since the last step
-            for i, s in enumerate(self.slots):
-                if not s.free and s.prefilling:
-                    n_sh = self.kv.share(i, s.req.prompt, s.pos)
-                    if n_sh:
-                        s.pos += n_sh
-                        self._shared_step += n_sh
-        drafts = self._propose()
-        n = self._schedule(drafts)
-        decode_toks = prefill_toks = deferred = draft_toks = accepted_toks = 0
+    def _share_prefixes(self) -> None:
+        """Lazy prefix sharing: an older request may have finished
+        writing pages a prefilling prompt can map since the last step."""
+        if self.kv is None:
+            return
+        for i, s in enumerate(self.slots):
+            if not s.free and s.prefilling:
+                n_sh = self.kv.share(i, s.req.prompt, s.pos)
+                if n_sh:
+                    s.pos += n_sh
+                    self._shared_step += n_sh
+
+    def _grants(self, n, drafts, st: StepStats):
+        """The step's grants from the scheduled token counts ``n``:
+        ``(grants, granted drafts by slot, out_base by slot)``.  Counts
+        the decode, draft, prefill and deferred tokens into ``st``."""
         grants: List[packing.Grant] = []  # (slot, start pos, tokens)
         granted_draft: Dict[int, List[int]] = {}
         # slot -> output index of the grant's first column's prediction:
@@ -857,13 +899,13 @@ class ContinuousBatcher:
         for i, s in enumerate(self.slots):
             if s.free or n[i] == 0:
                 if not s.free and s.prefilling:
-                    deferred += min(self.chunk_size, len(s.req.prompt) - s.pos)
+                    st.deferred_tokens += min(self.chunk_size, len(s.req.prompt) - s.pos)
                 continue
             r = s.req
             if s.prefilling:
                 toks = r.prompt[s.pos : s.pos + n[i]]
-                prefill_toks += n[i]
-                deferred += max(
+                st.prefill_tokens += n[i]
+                st.deferred_tokens += max(
                     min(self.chunk_size, len(r.prompt) - s.pos) - n[i], 0
                 )
             else:
@@ -871,27 +913,17 @@ class ContinuousBatcher:
                 draft = drafts.get(i, [])[: n[i] - 1]
                 granted_draft[i] = draft
                 toks = [r.output[-1] if r.output else r.prompt[-1]] + draft
-                decode_toks += 1
-                draft_toks += len(draft)
+                st.decode_tokens += 1
+                st.draft_tokens += len(draft)
             out_base[i] = s.pos + 1 - len(r.prompt)
             grants.append((i, s.pos, toks))
+        return grants, granted_draft, out_base
 
-        if self.kv is not None:
-            # allocate (and copy-on-write, if any page is shared) every
-            # page this step's grants will scatter into, then hand the
-            # refreshed block tables to the jitted step
-            self.kv.prepare_step(grants)
-            self.cache = self.kv.state
-        used_pages = self.kv.used_pages if self.kv is not None else 0
-
-        sampled = (
-            self._run_packed(grants, out_base)
-            if self.packed
-            else self._run_dense(grants, out_base)
-        )
-        if self.kv is not None:
-            self.kv.state = self.cache
-
+    def _emit(self, n, sampled, granted_draft) -> int:
+        """Commit the step's sampled tokens: advance positions, publish
+        prompt pages, accept drafts, finish requests.  Returns the draft
+        tokens accepted."""
+        accepted_toks = 0
         now = time.perf_counter()
         for i, s in enumerate(self.slots):
             if s.free or n[i] == 0:
@@ -944,25 +976,67 @@ class ContinuousBatcher:
                     self.kv.free_slot(i)
                 if self.spec is not None:
                     self.spec.proposer.free_slot(i)
+        return accepted_toks
 
-        scheduled = decode_toks + draft_toks + prefill_toks
-        stats = StepStats(
-            self.steps, decode_toks, prefill_toks, deferred, now - t0,
-            shared_tokens=self._shared_step,
-            used_pages=used_pages,
-            draft_tokens=draft_toks,
-            accepted_tokens=accepted_toks,
-            queued_requests=queued0,
-            budget_overshoot=(
-                max(scheduled - self.token_budget, 0)
-                if self.token_budget is not None else 0
-            ),
-            expert_overflow=self._overflow_step,
+    def step(self):
+        """One engine iteration: mixed chunked-prefill + decode/verify.
+
+        The iteration is one ``engine:step`` span, and each host phase a
+        span inside it (``serve.spans``) timed into the step's
+        ``StepStats.phases``; the phases cover ``wall_time``.  The step
+        span's trace arguments are the step index, its ``kind``, the
+        scheduled ``tokens`` and, when a request was left queued, why
+        admission stopped (``admit``).
+        """
+        st = StepStats(
+            self.steps, 0, 0, 0, 0.0, started_at=time.perf_counter(),
+            queued_requests=len(self.queue),  # depth before admission
         )
-        self.step_stats.append(stats)
+        self._shared_step = 0
+        self._overflow_step = 0  # set by the step runner from the jit aux
+        self._phases = ph = st.phases
+        with span("engine:step", step=st.step) as ann:
+            with span("engine:admit", ph):
+                st.admit_blocked = self._admit()
+            with span("engine:share", ph):
+                self._share_prefixes()
+            with span("engine:propose", ph):
+                drafts = self._propose()
+            with span("engine:schedule", ph):
+                n = self._schedule(drafts)
+                grants, granted_draft, out_base = self._grants(n, drafts, st)
+                wide = any(len(toks) > 1 for _, _, toks in grants)
+                st.kind = "mixed" if wide else "decode"
+            with span("engine:kv_prepare", ph):
+                if self.kv is not None:
+                    # allocate (and copy-on-write, if any page is shared)
+                    # every page this step's grants will scatter into,
+                    # then hand the refreshed block tables to the step
+                    self.kv.prepare_step(grants)
+                    self.cache = self.kv.state
+                    st.used_pages = self.kv.used_pages
+            sampled = (
+                self._run_packed(grants, out_base)
+                if self.packed
+                else self._run_dense(grants, out_base)
+            )
+            with span("engine:emit", ph):
+                if self.kv is not None:
+                    self.kv.state = self.cache
+                st.accepted_tokens = self._emit(n, sampled, granted_draft)
+                st.shared_tokens = self._shared_step
+                st.expert_overflow = self._overflow_step
+                if self.token_budget is not None:
+                    st.budget_overshoot = max(
+                        st.scheduled_tokens - self.token_budget, 0
+                    )
+            ann.set_metadata(kind=st.kind, tokens=st.scheduled_tokens,
+                             admit=st.admit_blocked or "")
+        st.wall_time = time.perf_counter() - st.started_at
+        self.step_stats.append(st)
         self.steps += 1
         for fn in self._step_callbacks:
-            fn(stats)
+            fn(st)
 
     def run(self, max_steps: int = 10_000) -> Dict[int, Request]:
         steps = 0
@@ -1011,9 +1085,12 @@ class ContinuousBatcher:
         def pct(values, q):
             return float(np.quantile(values, q)) if values else float("nan")
 
+        def mean(values):
+            return float(np.mean(values)) if values else float("nan")
+
         def dist(prefix, values):
             return {
-                f"mean_{prefix}": float(np.mean(values)) if values else float("nan"),
+                f"mean_{prefix}": mean(values),
                 f"p50_{prefix}": pct(values, 0.50),
                 f"p99_{prefix}": pct(values, 0.99),
             }
@@ -1080,6 +1157,14 @@ class ContinuousBatcher:
             ),
             "deferred_tokens": float(sum(s.deferred_tokens for s in st)),
             "max_step_wall": float(max((s.wall_time for s in st), default=0.0)),
+            # the host / sync split of a step: wall less sync is the
+            # host's own work, sync its wait on the device
+            "mean_step_wall": mean([s.wall_time for s in st]),
+            "mean_step_sync": mean([s.sync_time for s in st]),
+            **{
+                f"mean_phase_{k}": mean([s.phases.get(k, 0.0) for s in st])
+                for k in sorted({k for s in st for k in s.phases})
+            },
             "finished": float(len(done)),
             "mean_ttft": float(np.mean(ttfts)) if ttfts else float("nan"),
             "p50_ttft": pct(ttfts, 0.50),

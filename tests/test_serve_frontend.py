@@ -486,17 +486,29 @@ class TestStepCallbacks:
         assert seen is not eng.step_stats and seen == eng.step_stats
 
     def test_frontend_step_log_mirrors_engine(self, params):
+        """Driven through the front-end, the engine's own step log
+        (``step_stats``, the one per-step record) holds every step with
+        its phases and why admission stopped."""
         async def go():
-            eng = make_engine(params)
+            eng = make_engine(params)  # 2 slots, 4 requests at once
             async with AsyncEngine(eng) as fe:
-                s = await fe.submit(make_prompts()[0], 4)
-                await s.collect()
+                streams = [await fe.submit(p, 4) for p in make_prompts()[:4]]
+                await asyncio.gather(*(s.collect() for s in streams))
                 return fe
 
         fe = asyncio.run(go())
-        assert len(fe.step_log) == fe.engine.steps
-        # queue depth at step start is recorded for queue-pressure stats
-        assert all(s.queued_requests >= 0 for s in fe.step_log)
+        log = fe.engine.step_stats
+        assert len(log) == fe.engine.steps > 0
+        assert [s.step for s in log] == list(range(len(log)))
+        for s in log:
+            assert {"admit", "schedule", "dispatch", "sync", "emit"} <= set(s.phases)
+            assert 0 < sum(s.phases.values()) <= s.wall_time
+            # queue depth at step start is recorded for queue-pressure stats
+            assert s.queued_requests >= 0
+            assert s.admit_blocked in (None, "slots")
+            assert (s.admit_blocked is None) or s.queued_requests > 0
+        assert "slots" in [s.admit_blocked for s in log]
+        assert log[-1].admit_blocked is None
 
 
 # ---------------------------------------------------------------------------
