@@ -168,78 +168,175 @@ def flash_attention(
 # ---------------------------------------------------------------------------
 
 
+# keys a loop iteration meets: 512 // page_size pages.  On a v5e a 512-key
+# tile took 0.59 ms a layer for a docs-like mixed step where 128 keys took
+# 0.91, and 0.29 ms against 0.53 for a decode step; 1,024 keys gained
+# under 10% more for twice the unrolled page loads.
+PAGED_KEY_TILE = 512
+PAGED_MAX_ROWS = 256  # query rows (tokens x group) a block may hold
+PAGED_NARROW_ROWS = 16  # a block with no more real rows takes the narrow path
+# concurrent DMAs that stage one KV head's K pool, and as many its V pool:
+# on a v5e, 8 staged a 1,184-page pool for a decode-capacity call in 0.15 ms
+# where a double-buffered block spec (one DMA each) took 0.22
+PAGED_STAGE_DMAS = 8
+# two heads' staged pools, double-buffered, outgrow the compiler's 16 MiB
+# default for a kernel at the cells' 1,184 pages
+PAGED_VMEM_LIMIT = 32 * 2**20
+
+
+def paged_block_tokens(t: int, num_slots: int, g: int) -> int:
+    """Query tokens per block, from the call's static shape alone.
+
+    One slot can hold at most ``t - num_slots + 1`` of the ``t`` query
+    tokens while every other slot holds one; the block is the largest
+    power of two not above that, capped at ``PAGED_MAX_ROWS`` rows, and
+    never under 8 rows.  A decode-capacity call (``t <= num_slots``)
+    gets the smallest block; a packed call with room for prefill chunks
+    gets 128-token blocks (at a group of 2).
+    """
+    most = max(t - num_slots + 1, 1)
+    bq = min(1 << (most.bit_length() - 1), max(PAGED_MAX_ROWS // g, 1))
+    return max(bq, -(-8 // g))
+
+
+def paged_block_bound(t: int, num_slots: int, bq: int) -> int:
+    """Most blocks any layout of ``t`` tokens over ``num_slots`` slots
+    needs: a slot holding n tokens takes ceil(n / bq) blocks, and at most
+    min(num_slots, t) slots hold any, so the sum is at most
+    (t + min(num_slots, t) * (bq - 1)) // bq."""
+    return max((t + min(num_slots, t) * (bq - 1)) // bq, 1)
+
+
 def _paged_attn_kernel(
-    tbl_ref, pos_ref, slot_ref, q_ref, k_ref, v_ref, *rest,
-    page_size, num_pages, num_blocks, window, sm_scale, softcap, quantized,
+    start_ref, okbits_ref, slot_ref, lo_ref, hi_ref, rows_ref, q_ref, qpos_ref,
+    k_hbm, v_hbm, *rest, page_size, num_pages, pages_per_tile, blocks_per_slot,
+    window, sm_scale, softcap, quantized,
 ):
     if quantized:
-        ks_ref, vs_ref, o_ref = rest
+        ks_ref, vs_ref, o_ref, k_buf, v_buf, sem = rest
     else:
-        (o_ref,) = rest
-    # scalar-prefetched (SMEM) addressing for this program's query token
-    t = pl.program_id(1)
-    pos = pos_ref[t]
-    slot = slot_ref[t]
-    valid_q = slot >= 0
-    slot_s = jnp.maximum(slot, 0)
-    q = q_ref[...].astype(jnp.float32) * sm_scale  # (G, D): the KV head's group
+        o_ref, k_buf, v_buf, sem = rest
+    # Stage the head's pool into VMEM at its first block, and start the
+    # next head's into the other buffer: heads run outermost, so each
+    # head's pool is fetched once a call, behind the previous head's work.
+    j, nh = pl.program_id(0), pl.num_programs(0)
+    n_dma = sem.shape[-1]
+    edges = [round(c * num_pages / n_dma) * page_size for c in range(n_dma + 1)]
 
-    # Admissible logical-block range for this query: its slot's blocks up
-    # to (and including) its own position's block, lower-bounded by the
-    # sliding window.  Skipped blocks cost nothing — decode reads exactly
-    # ceil((pos+1)/page_size) pages, not the whole pool.
-    hi = jnp.where(valid_q, jnp.minimum(pos // page_size + 1, num_blocks), 0)
-    if window > 0:
-        lo = jnp.maximum((pos - window + 1) // page_size, 0)
+    def staging(head, buf):
+        out = []
+        for c in range(n_dma):
+            r = pl.ds(edges[c], edges[c + 1] - edges[c])
+            out.append(pltpu.make_async_copy(k_hbm.at[head, r], k_buf.at[buf, r], sem.at[buf, 0, c]))
+            out.append(pltpu.make_async_copy(v_hbm.at[head, r], v_buf.at[buf, r], sem.at[buf, 1, c]))
+        return out
+
+    cur = j % 2
+    first = pl.program_id(1) == 0
+
+    @pl.when(first & (j == 0))
+    def _():
+        for c in staging(0, 0):
+            c.start()
+
+    @pl.when(first)
+    def _():
+        for c in staging(j, cur):
+            c.wait()
+
+        @pl.when(j + 1 < nh)
+        def _():
+            for c in staging(j + 1, 1 - cur):
+                c.start()
+
+    k_ref, v_ref = k_buf.at[cur], v_buf.at[cur]
+    # scalar-prefetched (SMEM) addressing for this program's query block
+    b = pl.program_id(1)
+    slot, lo, hi = slot_ref[b], lo_ref[b], hi_ref[b]
+    tk = pages_per_tile * page_size
+    cdt = q_ref.dtype if quantized else jnp.promote_types(q_ref.dtype, k_ref.dtype)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, tk), 1)
+    lane_page = lane // page_size  # the tile's page that holds each key
+    n_words = -(-pages_per_tile // 32)
+
+    def attend(rows):
+        q = q_ref[:rows, :].astype(cdt)  # (rows, D): tokens x group, as stored
+        qpos = qpos_ref[:rows, :]  # (rows, 1); -1 on a padding row
+
+        def body(ki, carry):
+            acc, m_prev, l_prev = carry
+            # one key tile: pages_per_tile entries of the slot's table, each
+            # the first row of a page_size-row slice of the staged pool.
+            # Unallocated (num_pages) and hostile (negative) entries were
+            # clamped into the pool by the wrapper, so they only ever read
+            # a page that their bit, one 32-page word at a time, masks.
+            base = slot * blocks_per_slot + ki * pages_per_tile
+            page_ok = None
+            for w in range(n_words):
+                word = jnp.full((1, tk), okbits_ref[(base // pages_per_tile) * n_words + w],
+                                jnp.int32)
+                bit = jax.lax.shift_right_logical(word, lane_page % 32) & 1
+                page_ok = bit if page_ok is None else jnp.where(lane_page // 32 == w, bit, page_ok)
+            k_parts, v_parts = [], []
+            for p in range(pages_per_tile):
+                start = pl.multiple_of(start_ref[base + p], page_size)
+                k_parts.append(k_ref[pl.ds(start, page_size), :])
+                v_parts.append(v_ref[pl.ds(start, page_size), :])
+            k_tile = jax.lax.concatenate(k_parts, 0).astype(cdt)  # (tk, D)
+            v_tile = jax.lax.concatenate(v_parts, 0).astype(jnp.float32)
+            s = jax.lax.dot_general(
+                q, k_tile, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            ) * sm_scale  # (rows, tk)
+            if quantized:
+                # int8 pages: per-row dequant scales, gathered by the
+                # table into the slot's logical row, applied to the
+                # scores and to the softmax weights -- the same sums as
+                # scaling the K/V rows, without the (tk, D) multiplies
+                s = s * ks_ref[slot, pl.ds(ki, 1), :]
+            if softcap > 0.0:
+                s = softcap * jnp.tanh(s / softcap)
+            kpos = ki * tk + lane
+            mask = (kpos <= qpos) & (page_ok != 0)
+            if window > 0:
+                mask &= kpos > qpos - window
+            s = jnp.where(mask, s, NEG_INF)
+            m_cur = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_cur)
+            # mask p explicitly: when every position so far is masked, m_cur
+            # is still NEG_INF and exp(s - m_cur) would be 1, not 0 -- a
+            # fully-masked query must come out all-zero
+            p = jnp.where(mask, jnp.exp(s - m_cur), 0.0)
+            l_cur = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
+            if quantized:
+                p = p * vs_ref[slot, pl.ds(ki, 1), :]
+            # p stays float32 into the product: no rounding of the weights
+            acc = acc * alpha + jnp.dot(p, v_tile, preferred_element_type=jnp.float32)
+            return acc, m_cur, l_cur
+
+        d = q.shape[-1]
+        acc0 = jnp.zeros((rows, d), jnp.float32)
+        m0 = jnp.full((rows, 1), NEG_INF, jnp.float32)
+        l0 = jnp.zeros((rows, 1), jnp.float32)
+        acc, _, l = jax.lax.fori_loop(lo, hi, body, (acc0, m0, l0))
+        o_ref[:rows, :] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+
+    full = q_ref.shape[0]
+    if full <= PAGED_NARROW_ROWS:
+        attend(full)
     else:
-        lo = 0
+        # a block holding a decode or a short verify run computes on its
+        # first PAGED_NARROW_ROWS rows only; its other rows are padding
+        # that the wrapper never reads back
+        narrow = rows_ref[b] <= PAGED_NARROW_ROWS
 
-    def body(bi, carry):
-        acc, m_prev, l_prev = carry
-        page = tbl_ref[slot_s * num_blocks + bi]
-        # unallocated sentinel (num_pages) AND hostile negatives: a bad
-        # table entry may only redirect the read to a masked tile, never
-        # wrap around into another slot's pages
-        ok = (page >= 0) & (page < num_pages)
-        page_s = jnp.clip(page, 0, num_pages - 1)
-        start = pl.multiple_of(page_s * page_size, page_size)
-        k_tile = k_ref[pl.ds(start, page_size), :].astype(jnp.float32)  # (ps, d)
-        v_tile = v_ref[pl.ds(start, page_size), :].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k_tile, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )  # (G, ps)
-        if quantized:
-            # int8 pages: per-row dequant scales (per (page-row, kv-head),
-            # written at quantization time alongside the int8 rows) applied
-            # to the scores and to the softmax weights — the same sums as
-            # scaling the K/V rows, without the (ps, d) multiplies
-            s = s * ks_ref[pl.ds(page_s, 1), :]
-        if softcap > 0.0:
-            s = softcap * jnp.tanh(s / softcap)
-        kpos = bi * page_size + jax.lax.broadcasted_iota(jnp.int32, (1, page_size), 1)
-        mask = (kpos <= pos) & ok
-        if window > 0:
-            mask &= kpos > pos - window
-        s = jnp.where(mask, s, NEG_INF)
-        m_cur = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_cur)
-        # mask p explicitly: when every position so far is masked, m_cur
-        # is still NEG_INF and exp(s - m_cur) would be 1, not 0 — a
-        # fully-masked query must come out all-zero
-        p = jnp.where(mask, jnp.exp(s - m_cur), 0.0)
-        l_cur = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        if quantized:
-            p = p * vs_ref[pl.ds(page_s, 1), :]
-        acc = acc * alpha + jnp.dot(p, v_tile, preferred_element_type=jnp.float32)
-        return acc, m_cur, l_cur
+        @pl.when(narrow)
+        def _():
+            attend(PAGED_NARROW_ROWS)
 
-    g, d = q.shape
-    acc0 = jnp.zeros((g, d), jnp.float32)
-    m0 = jnp.full((g, 1), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((g, 1), jnp.float32)
-    acc, m, l = jax.lax.fori_loop(lo, hi, body, (acc0, m0, l0))
-    o_ref[...] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+        @pl.when(jnp.logical_not(narrow))
+        def _():
+            attend(full)
 
 
 def paged_flash_attention(
@@ -261,33 +358,57 @@ def paged_flash_attention(
     ``(slot, position)``; under the ``repro.serve.kv`` Paged layout the
     physical row is ``(tables[slot, position // page_size], position %
     page_size)``.  Instead of materializing each token's logical buffer
-    (what the jnp path does), the kernel walks the query's *block table*:
-    one program per (KV head, token) runs the online-softmax loop for the
-    head's whole query group over that slot's admissible logical blocks
-    only, loading each key tile by its page id — the loop never reads
+    (what the jnp path does), the kernel walks the slot's *block table*
+    and loads each key tile by its page ids -- the loop never reads
     another slot's pages, so cross-request isolation is structural, not a
     mask (adversarially tested in ``tests/test_kernels.py``).  Entries
     with ``tables[s, b] >= num_pages`` (the unallocated sentinel) or
     ``< 0`` are mask-dropped; padding queries (``q_slots < 0``) return
     zero rows.
 
-    TPU blocking: the block tables, positions and slots are scalar
-    prefetch (SMEM), so each program reads its addressing as scalars;
-    queries are blocked ``(G, D)`` per (KV head, token) — whole last two
-    dimensions, as the TPU compiler requires.  The grid runs KV heads
-    outermost, so a head's pool block stays resident while every token
-    attends to it.
+    Blocks: the wrapper sorts the query tokens by slot (stably, padding
+    last) and cuts each slot's tokens into blocks of ``Bq``
+    (``paged_block_tokens``: from ``T``, the slot count and the group
+    size G alone -- 4 tokens at G = 2 for a decode-capacity call, 128
+    for a packed step with prefill room).  Every block belongs to one
+    slot, in any layout: a slot in two separate runs, or every token a
+    run of its own, only merges or splits blocks, and each row keeps its
+    own position for the mask.  At most ``(T + min(S, T) * (Bq - 1)) //
+    Bq`` blocks exist (``paged_block_bound``), which sizes the gathered
+    ``(KV, blocks, Bq * G, D)`` query array, so no token is ever dropped;
+    the grid's second axis is the number of blocks actually formed
+    (a dynamic grid bound), so empty blocks cost nothing.
 
-    Like the dense kernel above (whole-K block specs), each program
-    *stages* the full per-head pool as one VMEM block and prunes reads
-    inside it, which bounds the pool by the compiler's scoped VMEM limit
-    (its default, not the chip's whole VMEM).  At internlm2-1.8b widths
-    (8 KV heads, head_dim 128, 16-token pages, 64 query tokens) the
-    largest pool that compiles for a v5e is 1,792 pages (28,672 tokens)
-    with bf16 pages and 3,184 pages (50,944 tokens) with int8 pages; 8
-    pages more and the compiler runs out of VMEM.  Lifting that bound
-    needs a kernel that DMAs pages by table entry from an HBM-resident
-    pool.
+    Grid ``(KV heads, blocks)``: one program per (KV head, query block)
+    runs the online softmax for the block's ``Bq * G`` rows (the head's
+    whole query group) over key tiles of ``512 // page_size`` pages (512
+    keys), from the window's lower bound for the block's first query to
+    the causal bound of its last; a per-row mask handles the rest.  A
+    block with at most 16 real rows (a decode, a short verify run)
+    computes on those 16 rows only.  ``q . K^T`` multiplies the operands
+    as stored (bf16) with float32 accumulation and scales the float32
+    scores; the softmax state and the weights ``p`` stay float32 into the
+    ``p . V`` product.  Scalar prefetch (SMEM) holds each table entry's
+    first row in the staged pool, which entries name a real page (packed
+    as bits, one 32-bit word per 32 pages of a key tile, so a tile's page
+    mask is one shift per word), and the per-block slot, loop bounds and
+    row counts: the kernel's per-page work is two loads, which keeps its
+    lowering short (the key tile's pages are unrolled).
+
+    Each KV head's pool is *staged* whole into VMEM by the kernel's own
+    DMAs (``PAGED_STAGE_DMAS`` concurrent copies each for K and V) from
+    HBM, where the wrapper keeps it: the head's first block waits for its
+    pool and starts the next head's into a second buffer, so each head's
+    pool is fetched once a call, behind the previous head's work, and the
+    kernel's device time holds its own reads.  Reads inside the staged
+    pool are pruned by the loop bounds.  Two heads' pools bound the pool
+    by the kernel's VMEM limit (``PAGED_VMEM_LIMIT``, 32 MiB).  At
+    internlm2-1.8b widths (8 KV heads, head_dim 128, 16-token pages, 32
+    slots of 256 blocks, 513 query tokens) the largest pool that compiles
+    for a v5e is 1,944 pages (31,104 tokens) with bf16 pages and 3,888
+    pages (62,208 tokens) with int8 pages; 8 pages more and the compiler
+    runs out of VMEM.  Lifting that bound needs a kernel that DMAs pages
+    by table entry from an HBM-resident pool.
 
     The jnp oracle is ``repro.kernels.ref.paged_attention_ref``; the
     fused XLA path used off-TPU is ``paged_attention_xla`` below.
@@ -302,52 +423,125 @@ def paged_flash_attention(
         # over raw int8 codes for one of K/V
         raise ValueError("pass both k_scale and v_scale, or neither")
 
+    bq = paged_block_tokens(t, num_slots, g)
+    nblk = paged_block_bound(t, num_slots, bq)
+    rows = bq * g
+    ppt = max(PAGED_KEY_TILE // page_size, 1)
+    tk = ppt * page_size
+    # tables padded with the sentinel to whole key tiles
+    bps = -(-num_blocks // ppt) * ppt
+    tables = jnp.pad(tables.astype(jnp.int32), ((0, 0), (0, bps - num_blocks)),
+                     constant_values=num_pages)
+    # which entries name a real page, as bits: word w of a key tile holds
+    # its pages 32w..32w+31 (distinct bits, so the sum is their OR)
+    n_words = -(-ppt // 32)
+    ok = ((tables >= 0) & (tables < num_pages)).reshape(num_slots, bps // ppt, ppt)
+    ok = jnp.pad(ok, ((0, 0), (0, 0), (0, n_words * 32 - ppt))).astype(jnp.uint32)
+    ok = ok.reshape(num_slots, bps // ppt, n_words, 32) << jnp.arange(32, dtype=jnp.uint32)
+    okbits = jax.lax.bitcast_convert_type(ok.sum(-1, dtype=jnp.uint32), jnp.int32)
+    # each entry's first row in the flat staged pool; entries the mask drops
+    # read page 0 or the last page, in range
+    starts = jnp.clip(tables, 0, num_pages - 1) * page_size
+
+    # --- blocks, in the graph: sort by slot, cut each slot's run at bq ---
+    valid = q_slots >= 0
+    key = jnp.where(valid, jnp.clip(q_slots, 0, num_slots - 1), num_slots)
+    order = jnp.argsort(key, stable=True)
+    key_s = key[order]
+    counts = jnp.zeros((num_slots + 1,), jnp.int32).at[key].add(1)
+    rank = jnp.arange(t) - (jnp.cumsum(counts) - counts)[key_s]
+    slot_blocks = -(-counts[:num_slots] // bq)
+    n_blocks = jnp.sum(slot_blocks)
+    slot_first = jnp.cumsum(slot_blocks) - slot_blocks
+    blk_s = jnp.where(key_s < num_slots,
+                      slot_first[jnp.minimum(key_s, num_slots - 1)] + rank // bq, nblk)
+    dest = jnp.zeros((t,), jnp.int32).at[order].set(blk_s * bq + rank % bq)
+    src = jnp.full((nblk * bq,), t, jnp.int32).at[dest].set(
+        jnp.arange(t, dtype=jnp.int32), mode="drop")  # token per block row; t = none
+    pos_s = q_pos.astype(jnp.int32)[order]
+    blk_slot = jnp.zeros((nblk,), jnp.int32).at[blk_s].set(key_s, mode="drop")
+    blk_rows = jnp.zeros((nblk,), jnp.int32).at[blk_s].add(g, mode="drop")
+    blk_max = jnp.zeros((nblk,), jnp.int32).at[blk_s].max(pos_s, mode="drop")
+    blk_hi = jnp.minimum(blk_max // tk + 1, bps // ppt)
+    if window > 0:
+        blk_min = jnp.full((nblk,), jnp.iinfo(jnp.int32).max, jnp.int32).at[blk_s].min(
+            pos_s, mode="drop")
+        blk_lo = jnp.maximum((blk_min - window + 1) // tk, 0)
+    else:
+        blk_lo = jnp.zeros((nblk,), jnp.int32)
+
+    # (KV, blocks, rows, D): a block's rows are its tokens x the head's group
+    qt = jnp.pad(q.reshape(t, kvh, g, d), ((0, 1), (0, 0), (0, 0), (0, 0)))
+    qb = qt.transpose(1, 0, 2, 3)[:, src].reshape(kvh, nblk, rows, d)
+    qpos = jnp.where(src < t, jnp.pad(q_pos.astype(jnp.int32), (0, 1))[src], -1)
+    qpos = jnp.repeat(qpos, g).reshape(nblk, rows, 1)
     # (KV, num_pages * page_size, D): one flat row pool per KV head, so a
     # page id turns into a row-slice start inside the kernel.
-    rows = num_pages * page_size
-    kr = k_pool.transpose(2, 0, 1, 3).reshape(kvh, rows, d)
-    vr = v_pool.transpose(2, 0, 1, 3).reshape(kvh, rows, d)
-    qr = q.reshape(t, kvh, g, d)
+    n_rows = num_pages * page_size
+    kr = k_pool.transpose(2, 0, 1, 3).reshape(kvh, n_rows, d)
+    vr = v_pool.transpose(2, 0, 1, 3).reshape(kvh, n_rows, d)
+    if not interpret:  # (the interpreter takes no memory-space constraint)
+        # The pools stay in HBM and the kernel's own DMAs stage them.
+        # Otherwise XLA may place one in VMEM ahead of the call (a v5e has
+        # room for a 38.8 MB pool), and its transfer then hides in the
+        # producing op's time instead of the kernel's.  A trade, not a
+        # speed-up: on a v5e XLA's placement with a block spec made a chat
+        # decode step 18.35 ms, this 20.29.  It exists because
+        # bench/metrics/paged_attn_roofline.docs.py charges the pool's
+        # reads to the kernel; once that reader stops depending on where
+        # the pools live, drop this pin, the staging DMAs and
+        # test_paged_pools_stay_in_hbm together.
+        kr, vr = (pltpu.with_memory_space_constraint(x, pltpu.HBM) for x in (kr, vr))
 
     kernel = functools.partial(
         _paged_attn_kernel,
-        page_size=page_size, num_pages=num_pages, num_blocks=num_blocks,
-        window=window, sm_scale=1.0 / math.sqrt(d), softcap=softcap,
-        quantized=quantized,
+        page_size=page_size, num_pages=num_pages, pages_per_tile=ppt,
+        blocks_per_slot=bps, window=window, sm_scale=1.0 / math.sqrt(d),
+        softcap=softcap, quantized=quantized,
     )
     # index maps take (grid indices..., *scalar prefetch refs)
     in_specs = [
-        pl.BlockSpec((None, None, g, d), lambda j, i, *_: (i, j, 0, 0)),
-        pl.BlockSpec((None, rows, d), lambda j, i, *_: (j, 0, 0)),
-        pl.BlockSpec((None, rows, d), lambda j, i, *_: (j, 0, 0)),
+        pl.BlockSpec((None, None, rows, d), lambda j, i, *_: (j, i, 0, 0)),
+        pl.BlockSpec((None, rows, 1), lambda j, i, *_: (i, 0, 0)),
+        pl.BlockSpec(memory_space=pl.ANY),  # staged by the kernel itself
+        pl.BlockSpec(memory_space=pl.ANY),
     ]
-    operands = [qr, kr, vr]
+    operands = [qb, qpos, kr, vr]
     if quantized:
-        # Per-row dequant scales, one (num_pages, page_size) plane per KV
-        # head: a page id is a row of it.
+        # Per-row dequant scales gathered by the (padded) table into one
+        # (slots, key tiles, tk) plane per KV head, so a key tile's scales
+        # are one row of it; entries the table does not own are masked in
+        # the kernel like their pages.
+        safe = jnp.clip(tables, 0, num_pages - 1)
         for sc in (k_scale, v_scale):
-            in_specs.append(
-                pl.BlockSpec((None, num_pages, page_size), lambda j, i, *_: (j, 0, 0))
-            )
-            operands.append(sc.transpose(2, 0, 1).astype(jnp.float32))
+            plane = sc.astype(jnp.float32).transpose(2, 0, 1)[:, safe]
+            in_specs.append(pl.BlockSpec((None, num_slots, bps // ppt, tk),
+                                         lambda j, i, *_: (j, 0, 0, 0)))
+            operands.append(plane.reshape(kvh, num_slots, bps // ppt, tk))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(kvh, t),
+        num_scalar_prefetch=6,
+        grid=(kvh, n_blocks),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((None, None, g, d), lambda j, i, *_: (i, j, 0, 0)),
+        out_specs=pl.BlockSpec((None, None, rows, d), lambda j, i, *_: (j, i, 0, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((2, n_rows, d), k_pool.dtype),
+            pltpu.VMEM((2, n_rows, d), v_pool.dtype),
+            pltpu.SemaphoreType.DMA((2, 2, min(PAGED_STAGE_DMAS, num_pages))),
+        ],
     )
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((t, kvh, g, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((kvh, nblk, rows, d), q.dtype),
         interpret=interpret,
-    )(
-        tables.astype(jnp.int32).reshape(num_slots * num_blocks),
-        q_pos.astype(jnp.int32),
-        q_slots.astype(jnp.int32),
-        *operands,
-    )
-    return out.reshape(t, h, d)
+        name="paged_flash_attention",
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=PAGED_VMEM_LIMIT),
+    )(starts.reshape(num_slots * bps), okbits.reshape(-1), blk_slot, blk_lo, blk_hi,
+      blk_rows, *operands)
+    # blocks past n_blocks are never written, and no token's row is there
+    out = out.reshape(kvh, nblk * bq, g, d)[:, jnp.clip(dest, 0, nblk * bq - 1)]
+    out = jnp.where(valid[None, :, None, None], out, 0).astype(q.dtype)
+    return out.transpose(1, 0, 2, 3).reshape(t, h, d)
 
 
 def paged_attention_xla(

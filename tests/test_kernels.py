@@ -10,6 +10,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.kernels import flash_attention as fa
 from repro.kernels import ops, ref
 
 
@@ -377,6 +378,100 @@ class TestPagedAttention:
         sel = np.asarray(slots) == 0
         np.testing.assert_array_equal(out[sel], clean[sel])
         assert np.abs(out[sel]).max() < 1e3, "foreign int8 pages leaked"
+
+    # (slot, first position, tokens) runs in packed order; 4 slots of 48
+    # 16-token pages, so each slot's table spans two key tiles
+    # (fa.PAGED_KEY_TILE = 512 keys) and query blocks (paged_block_tokens:
+    # 16 tokens at 24 query tokens, 4 slots, G = 2) both have boundaries
+    # inside the runs.  With window 24, a block whose first query lies past
+    # 512 + 24 starts its key loop at the second tile (lo > 0).
+    LAYOUTS = {
+        # the decode-capacity shape: one token per slot, 4 tokens; slot 3
+        # reads its second key tile
+        "all_decode": (4, [(0, 150, 1), (1, 37, 1), (2, 5, 1), (3, 700, 1)]),
+        # starts mid-page, crosses pages, the 512-key tile and a block
+        "long_chunk": (24, [(1, 500, 20)]),
+        # a cell's mixed step in little: decodes between two chunks, one
+        # chunk wholly in the second key tile
+        "chunks_and_decodes": (24, [(1, 90, 1), (0, 60, 8), (3, 170, 1),
+                                    (2, 560, 10)]),
+        # slot 2 in two runs, a key tile apart, around another slot's token
+        "split_slot": (24, [(2, 40, 6), (0, 100, 1), (2, 540, 4)]),
+        # every token a run of its own: the slots alternate, across 512
+        "token_runs": (24, [(i % 4, 490 + 7 * (i // 4), 1) for i in range(24)]),
+        "all_padding": (24, []),
+    }
+
+    @classmethod
+    def layout_scenario(cls, name, kv_dtype, seed=0, page_size=16, kvh=2, h=4, d=16):
+        t, runs = cls.LAYOUTS[name]
+        rng = np.random.default_rng(seed)
+        num_slots, nb = 4, 768 // page_size  # 48 pages of 16
+        num_pages = num_slots * nb
+        q_slots = np.full(t, -1, np.int32)
+        q_pos = np.zeros(t, np.int32)
+        c = 0
+        for s, p0, n in runs:
+            q_slots[c:c + n], q_pos[c:c + n] = s, np.arange(p0, p0 + n)
+            c += n
+        tables = np.full((num_slots, nb), num_pages, np.int32)
+        for s in range(num_slots):
+            live = q_pos[q_slots == s].max(initial=0) // page_size + 1
+            tables[s, :live] = [s + j * num_slots for j in range(live)]
+        k_pool = rng.normal(size=(num_pages, page_size, kvh, d)).astype(np.float32)
+        v_pool = rng.normal(size=(num_pages, page_size, kvh, d)).astype(np.float32)
+        q = jnp.asarray(rng.normal(size=(t, h, d)), jnp.bfloat16)
+        addr = tuple(jnp.asarray(a) for a in (tables, q_pos, q_slots))
+        if kv_dtype == "int8":
+            (kq, ks), (vq, vs) = cls.quantize_pool(k_pool), cls.quantize_pool(v_pool)
+            return (q, kq, vq) + addr, {"k_scale": ks, "v_scale": vs}
+        pools = tuple(jnp.asarray(p, jnp.bfloat16) for p in (k_pool, v_pool))
+        return (q,) + pools + addr, {}
+
+    @pytest.mark.parametrize("softcap", [0.0, 2.0])
+    @pytest.mark.parametrize("window", [0, 24])
+    @pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
+    @pytest.mark.parametrize("layout", sorted(LAYOUTS))
+    def test_layouts_match_ref(self, layout, kv_dtype, window, softcap):
+        """Every packed layout a caller can pass -- runs of any length,
+        a slot in several runs, every token its own run, no tokens at
+        all -- comes out as the oracle says: no token dropped, padding
+        rows zero."""
+        args, scales = self.layout_scenario(layout, kv_dtype)
+        out = np.asarray(ops.paged_flash_attention(
+            *args, window=window, softcap=softcap, interpret=True, **scales), np.float32)
+        expect = np.asarray(ref.paged_attention_ref(
+            *args, window=window, softcap=softcap, **scales), np.float32)
+        np.testing.assert_allclose(out, expect, atol=2e-2)
+        pad = np.asarray(args[-1]) < 0
+        np.testing.assert_array_equal(out[pad], np.zeros_like(out[pad]))
+        assert np.abs(out[~pad]).max(initial=1.0) > 0  # real rows are not zeroed
+
+    @pytest.mark.parametrize("page_size", [4, 8])
+    def test_page_mask_words(self, page_size):
+        """Pages under 16 rows put more than 32 pages in a 512-key tile, so
+        the kernel reads which table entries are real from several 32-bit
+        words: hostile entries in every word, and in the second key tile,
+        stay masked as the oracle masks them."""
+        args, _ = self.layout_scenario("split_slot", "bf16", page_size=page_size)
+        q, kp, vp, tables, q_pos, q_slots = args
+        n = kp.shape[0]
+        per_tile = fa.PAGED_KEY_TILE // page_size
+        hostile = np.asarray(tables).copy()
+        # slot 2 reads to position 543: one entry in each word of its first
+        # tile and one in its second
+        cols = [5 + 32 * w for w in range(per_tile // 32)] + [per_tile + 1]
+        hostile[2, cols] = [-1, n + 3, -7, n][: len(cols) - 1] + [-1]
+        hostile = jnp.asarray(hostile)
+        out = np.asarray(ops.paged_flash_attention(
+            q, kp, vp, hostile, q_pos, q_slots, interpret=True), np.float32)
+        expect = np.asarray(ref.paged_attention_ref(
+            q, kp, vp, hostile, q_pos, q_slots), np.float32)
+        np.testing.assert_allclose(out, expect, atol=2e-2)
+        clean = np.asarray(ops.paged_flash_attention(
+            q, kp, vp, tables, q_pos, q_slots, interpret=True), np.float32)
+        slot2 = np.asarray(q_slots) == 2
+        assert np.abs(out[slot2] - clean[slot2]).max() > 1e-2  # the holes were dropped
 
 
 class TestSsdSegment:
