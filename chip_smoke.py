@@ -234,14 +234,18 @@ def train_phase(cfg, *, seq=512, workers=8, microbatches=4, rows=8, steps=6) -> 
 
 def compile_packed_steps(eng) -> tuple:
     """AOT-compile the engine's two packed programs (mixed and pure-decode
-    capacity); the engine's own calls then reuse them.  Returns (seconds,
+    capacity, the mixed one with its LM-head rows where it is wider than
+    them); the engine's own calls then reuse them.  Returns (seconds,
     HLO text of the mixed program)."""
     t0 = time.perf_counter()
     hlo = None
     for cap in (eng.packed_capacity, eng.packed_decode_capacity):
         vec = jax.ShapeDtypeStruct((cap,), jnp.int32)
+        head = (jax.ShapeDtypeStruct((eng.head_rows,), jnp.int32)
+                if cap > eng.head_rows else None)
         compiled = scheduler._packed_engine_step.lower(
-            eng.params, eng.cfg, eng.cache, vec, vec, vec, moe_impl=eng.moe_impl
+            eng.params, eng.cfg, eng.cache, vec, vec, vec, moe_impl=eng.moe_impl,
+            head_idx=head,
         ).compile()
         hlo = hlo or compiled.as_text()
     return time.perf_counter() - t0, hlo

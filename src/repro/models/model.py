@@ -419,6 +419,7 @@ def packed_prefill(
     positions: jnp.ndarray,  # (P,) int32 absolute cache position per token
     moe_impl: str = "dense",
     return_aux: bool = False,
+    head_idx: Optional[jnp.ndarray] = None,  # (R,) int32 rows to unembed
 ) -> Tuple[jnp.ndarray, PyTree]:
     """Token-packed engine step: granted tokens alone determine compute.
 
@@ -441,6 +442,12 @@ def packed_prefill(
     pack_step invariant that a slot's tokens are contiguous is what makes
     one global scan per step sound.  ``return_aux`` as in
     ``prefill_chunk``.
+
+    ``head_idx`` selects the packed rows that go through the final norm
+    and the LM head: logits are then ``(R, V)``, row ``r`` that of packed
+    row ``head_idx[r]``.  Rows mix only inside the layer stack, so the
+    gather changes no row's value; a step that samples few of its rows
+    (a prefill chunk emits at most its last) skips the head for the rest.
     """
     require_chunkable(cfg, "packed prefill")
     data, tables, page_size = _cache_parts(cache)
@@ -452,6 +459,8 @@ def packed_prefill(
         slot_ids=jnp.asarray(slot_ids), moe_impl=moe_impl,
         page_tables=tables, page_size=page_size,
     )
+    if head_idx is not None:
+        x = jnp.take(x, jnp.asarray(head_idx), axis=1)  # (1, R, d)
     x = L.apply_norm(params["final_norm"], x, cfg)
     logits = L.unembed(params["embed"], x, cfg)
     out_cache = _cache_rebuild(cache, {"stack": new_stack})

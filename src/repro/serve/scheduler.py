@@ -105,11 +105,13 @@ def _engine_step(params, cfg: ModelConfig, cache, tokens, pos, lens,
 
 @functools.partial(jax.jit, static_argnames=("cfg", "moe_impl"))
 def _packed_engine_step(params, cfg: ModelConfig, cache, tokens, slot_ids, pos,
-                        moe_impl: str = "dense"):
-    """Token-packed step: one (capacity,) program per engine config."""
+                        moe_impl: str = "dense", head_idx=None):
+    """Token-packed step: one (capacity,) program per engine config.
+    ``head_idx`` (R,) picks the rows that reach the LM head; None (the
+    decode-capacity program) unembeds every row."""
     return packed_prefill(
         params, cfg, cache, tokens, slot_ids, pos,
-        moe_impl=moe_impl, return_aux=True,
+        moe_impl=moe_impl, return_aux=True, head_idx=head_idx,
     )
 
 
@@ -246,6 +248,12 @@ class StepStats:
     #: skips the expert FFN).  Always 0 for dense dispatch and
     #: MoE-free configs.
     expert_overflow: int = 0
+    #: rows of the step's program (its packed capacity, or B x C dense)
+    #: and the rows of them that went through the LM head and sampler:
+    #: a packed program wider than ``ContinuousBatcher.head_rows`` sends
+    #: only the rows that can emit a token
+    rows: int = 0
+    head_rows: int = 0
 
     @property
     def scheduled_tokens(self) -> int:
@@ -446,6 +454,16 @@ class ContinuousBatcher:
         # program's budget-sized capacity — the same two-program design
         # as the dense engine's (B, chunk) + (B, 1) pair.
         self.packed_decode_capacity = batch_slots if packed else None
+        # Rows a step can emit from: every column of a decode slot's
+        # verify grant (1 + k), the last of a prefill chunk that completes
+        # its prompt, none of a chunk that ends mid-prompt.  A packed
+        # program wider than this gathers those rows before the LM head
+        # and samples only them; the decode-capacity program
+        # (batch_slots rows) is never wider.
+        self.head_rows = (
+            batch_slots * (1 + (self.spec.k if self.spec is not None else 0))
+            if packed else None
+        )
         self.dist = dist
         if dist is not None:
             params = dist.shard(params)
@@ -479,6 +497,7 @@ class ContinuousBatcher:
         self.step_stats: List[StepStats] = []
         self._shared_step = 0
         self._overflow_step = 0
+        self._rows_step = (0, 0)  # (program rows, head rows) of the step
         self._phases: Dict[str, float] = {}  # the running step's phases
         self._step_callbacks: List = []
 
@@ -826,7 +845,22 @@ class ContinuousBatcher:
             # free the step's device buffers here, not on return, so
             # the phases cover the step
             del logits, sampled, aux
+        self._rows_step = (b * c, b * c)
         return {i: next_tok[i, : len(toks)] for i, _, toks in grants}
+
+    def _emitting_rows(self, layout: packing.PackedLayout) -> List[int]:
+        """The packed rows whose samples the step can use: every column
+        of a decode slot's span (its token, or its 1 + k verify columns)
+        and the last column of a prefill chunk that completes its
+        prompt.  A chunk that ends mid-prompt emits nothing."""
+        rows: List[int] = []
+        for i, (j, m) in layout.spans.items():
+            s = self.slots[i]
+            if not s.prefilling:
+                rows.extend(range(j, j + m))
+            elif s.pos + m == len(s.req.prompt):
+                rows.append(j + m - 1)
+        return rows
 
     def _run_packed(self, grants, out_base) -> Dict[int, np.ndarray]:
         """Token-packed (capacity,) step: compute scales with grants.
@@ -837,6 +871,11 @@ class ContinuousBatcher:
         per-position key indices are slot-gathered per packed entry
         (``PackedLayout.out_idx``), so a packed row samples exactly what
         the dense row for the same (request, output index) samples.
+
+        A program wider than ``head_rows`` unembeds and samples only the
+        rows that can emit (``_emitting_rows``), padded to ``head_rows``
+        with greedy rows whose samples are dropped; a row it skipped
+        returns -1, which ``_emit`` never reads.
         """
         ph = self._phases
         with span("engine:pack", ph):
@@ -854,20 +893,36 @@ class ContinuousBatcher:
                 temps[j : j + m] = sp.temperature
                 topk[j : j + m] = sp.top_k
                 topp[j : j + m] = sp.top_p
+            out_idx, head_idx = layout.out_idx, None
+            if capacity > self.head_rows:
+                rows = self._emitting_rows(layout)
+                head_idx = np.zeros((self.head_rows,), np.int32)
+                head_idx[: len(rows)] = rows
+                seeds, out_idx, temps, topk, topp = (
+                    a[head_idx] for a in (seeds, out_idx, temps, topk, topp)
+                )
+                # padding: greedy rows, their samples dropped
+                temps[len(rows):], topk[len(rows):], topp[len(rows):] = 0.0, 0, 1.0
         with span("engine:dispatch", ph):
             logits, self.cache, aux = _packed_engine_step(
                 self.params, self.cfg, self.cache, jnp.asarray(layout.tokens),
                 jnp.asarray(layout.slot_ids), jnp.asarray(layout.positions),
                 moe_impl=self.moe_impl,
+                head_idx=None if head_idx is None else jnp.asarray(head_idx),
             )
-            sampled = sample_tokens(logits, seeds, layout.out_idx, temps, topk, topp)
+            sampled = sample_tokens(logits, seeds, out_idx, temps, topk, topp)
         with span("engine:sync", ph):
-            next_tok = np.asarray(sampled)  # (P,)
+            next_tok = np.asarray(sampled)  # (P,), or (R,) gathered
         with span("engine:sync_overflow", ph):
             self._overflow_step = int(np.asarray(aux["expert_overflow"]))
             # free the step's device buffers here, not on return, so
             # the phases cover the step
             del logits, sampled, aux
+        self._rows_step = (capacity, capacity if head_idx is None else len(head_idx))
+        if head_idx is not None:
+            full = np.full((capacity,), -1, np.int32)
+            full[rows] = next_tok[: len(rows)]
+            next_tok = full
         return {i: next_tok[j : j + m] for i, (j, m) in layout.spans.items()}
 
     def _share_prefixes(self) -> None:
@@ -985,8 +1040,9 @@ class ContinuousBatcher:
         span inside it (``serve.spans``) timed into the step's
         ``StepStats.phases``; the phases cover ``wall_time``.  The step
         span's trace arguments are the step index, its ``kind``, the
-        scheduled ``tokens`` and, when a request was left queued, why
-        admission stopped (``admit``).
+        scheduled ``tokens``, the program's ``rows`` and the ``head_rows``
+        of them that reached the LM head, and, when a request was left
+        queued, why admission stopped (``admit``).
         """
         st = StepStats(
             self.steps, 0, 0, 0, 0.0, started_at=time.perf_counter(),
@@ -1026,12 +1082,14 @@ class ContinuousBatcher:
                 st.accepted_tokens = self._emit(n, sampled, granted_draft)
                 st.shared_tokens = self._shared_step
                 st.expert_overflow = self._overflow_step
+                st.rows, st.head_rows = self._rows_step
                 if self.token_budget is not None:
                     st.budget_overshoot = max(
                         st.scheduled_tokens - self.token_budget, 0
                     )
             ann.set_metadata(kind=st.kind, tokens=st.scheduled_tokens,
-                             admit=st.admit_blocked or "")
+                             admit=st.admit_blocked or "", rows=st.rows,
+                             head_rows=st.head_rows)
         st.wall_time = time.perf_counter() - st.started_at
         self.step_stats.append(st)
         self.steps += 1

@@ -122,6 +122,39 @@ def test_recorded_engine_step(tmp_path):
     assert any("_greedy_tokens" in m for m in et.module_s)
 
 
+def test_recorded_engine_step_head_rows(tmp_path):
+    """The ``engine:step`` span carries the program's ``rows`` and the
+    ``head_rows`` of them that reached the LM head, as ``StepStats`` has
+    them: a mixed step sends one row a slot, a decode step all of them."""
+    from repro.models import ModelConfig
+    from repro.models.model import init_params
+    from repro.serve import ContinuousBatcher, Request
+
+    cfg = ModelConfig(name="bench-spans-t", n_layers=2, d_model=32, n_heads=2,
+                      n_kv_heads=1, d_ff=64, vocab_size=101, layer_pattern="LG",
+                      sliding_window=6, dtype="float32", remat=False)
+    eng = ContinuousBatcher(init_params(jax.random.PRNGKey(0), cfg), cfg,
+                            batch_slots=2, max_len=24, chunk_size=4, token_budget=8,
+                            packed=True, cache="paged", page_size=4)
+
+    def submit(uid0):
+        for i, n in enumerate((7, 2, 5)):
+            eng.submit(Request(uid=uid0 + i, prompt=list(range(1, n + 1)),
+                               max_new_tokens=3))
+
+    submit(0)
+    eng.run()  # compile outside the trace
+    n0 = eng.steps
+    submit(10)
+    spans, ops = _record(tmp_path, eng.run)
+    et = E.reduce(spans, ops)
+    got = [(st.kind, st.args["rows"], st.args["head_rows"]) for st in et.steps]
+    assert got == [(rec.kind, rec.rows, rec.head_rows) for rec in eng.step_stats[n0:]]
+    assert set(got) == {("mixed", 9, 2), ("decode", 2, 2)}
+    assert common.metric_reader("head_row_share.chat")(_run(et)) == pytest.approx(
+        100.0 * 2 / 9)
+
+
 def _step(t0, kind, wall, phases, admit=None):
     args = {"step": 0, "kind": kind, "tokens": 1}
     if admit:

@@ -175,6 +175,69 @@ class TestPackedModelPath:
             np.testing.assert_allclose(last_p[i], last_d[i], atol=1e-5)
             assert int(last_p[i].argmax()) == int(last_d[i].argmax())
 
+    def test_head_idx_selects_rows(self, params):
+        """``head_idx`` returns the logits of exactly those packed rows
+        (any order, repeats allowed) and leaves the cache as without it."""
+        grants = [(0, 0, make_prompts(seed=5, lens=(6,))[0]), (1, 0, [7])]
+        lay = pack_step(grants, capacity=10)
+        packed = [jnp.asarray(a) for a in (lay.tokens, lay.slot_ids, lay.positions)]
+        cache = init_decode_cache(params, CFG, 2, 24, linear=True)
+        full, cache_f = packed_prefill(params, CFG, cache, *packed)
+        idx = np.asarray([6, 5, 0, 6], np.int32)
+        part, cache_h = packed_prefill(params, CFG, cache, *packed,
+                                       head_idx=jnp.asarray(idx))
+        assert part.shape == (4, CFG.vocab_size)
+        np.testing.assert_allclose(part, np.asarray(full)[idx], atol=1e-6)
+        assert jax.tree.all(jax.tree.map(
+            lambda a, b: bool((np.asarray(a) == np.asarray(b)).all()), cache_f, cache_h))
+
+
+class TestHeadRows:
+    """Which packed programs gather the rows that can emit before the LM
+    head, and the counter that says so (``StepStats.rows``/``head_rows``)."""
+
+    def _engine(self, params, **kw):
+        return ContinuousBatcher(params, CFG, batch_slots=2, max_len=24, chunk_size=4,
+                                 packed=True, cache="paged", page_size=4, **kw)
+
+    def test_decode_program_is_untouched(self, params, monkeypatch):
+        from repro.serve import scheduler
+
+        eng = self._engine(params, token_budget=8)
+        assert (eng.head_rows, eng.packed_capacity, eng.packed_decode_capacity) == (2, 9, 2)
+        calls, step = [], scheduler._packed_engine_step
+
+        def spy(*a, head_idx=None, **kw):
+            calls.append((a[3].shape[0], None if head_idx is None else head_idx.shape))
+            return step(*a, head_idx=head_idx, **kw)
+
+        monkeypatch.setattr(scheduler, "_packed_engine_step", spy)
+        for i, p in enumerate(make_prompts(lens=(7, 2, 5))):
+            eng.submit(Request(uid=i, prompt=p, max_new_tokens=4))
+        eng.run()
+        assert set(calls) == {(2, None), (9, (2,))}
+        # the decode-capacity program without the argument and with None
+        # is one program
+        vec = jax.ShapeDtypeStruct((2,), jnp.int32)
+        lower = lambda **kw: step.lower(  # noqa: E731
+            eng.params, eng.cfg, eng.cache, vec, vec, vec, moe_impl="dense", **kw).as_text()
+        assert lower() == lower(head_idx=None)
+        assert lower() != lower(head_idx=vec)
+
+    def test_step_stats_count_head_rows(self, params):
+        eng = self._engine(params, token_budget=8)
+        for i, p in enumerate(make_prompts(lens=(7, 2, 5))):
+            eng.submit(Request(uid=i, prompt=p, max_new_tokens=4))
+        eng.run()
+        kinds = {(st.kind, st.rows, st.head_rows) for st in eng.step_stats}
+        assert kinds == {("decode", 2, 2), ("mixed", 9, 2)}
+        # the dense engine sends every row of its (B, C) program
+        dense = ContinuousBatcher(params, CFG, batch_slots=2, max_len=24, chunk_size=4)
+        dense.submit(Request(uid=0, prompt=make_prompts(lens=(7,))[0], max_new_tokens=3))
+        dense.run()
+        assert {(st.kind, st.rows, st.head_rows) for st in dense.step_stats} == {
+            ("mixed", 8, 8), ("decode", 2, 2)}
+
 
 class TestUnsupportedPatternTyped:
     """Non-chunkable configs raise the typed error cleanly (asserts would

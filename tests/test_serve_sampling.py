@@ -290,6 +290,76 @@ class TestSpecSampledExactness:
 
 
 # ---------------------------------------------------------------------------
+# the LM head and sampler see only the rows that can emit a token
+# ---------------------------------------------------------------------------
+
+
+TOP_K = SamplingParams(temperature=1.5, top_k=7, top_p=0.8)
+SHARED = make_prompts(seed=3, lens=(9,))[0]
+
+#: case -> (prompts, sampling, engine options); chunks of 4 tokens
+HEAD_CASES = {
+    "greedy": (make_prompts(), lambda i: SamplingParams(), dict(token_budget=16)),
+    "top_p": (make_prompts(), seeded, dict(token_budget=16)),
+    "top_k": (make_prompts(), lambda i: TOP_K.with_seed(50 + i), dict(token_budget=16)),
+    # every prompt completes exactly at the end of a chunk
+    "chunk_boundary": (make_prompts(lens=(8, 12, 4, 16)), seeded, dict()),
+    # every prompt completes inside a chunk
+    "mid_chunk": (make_prompts(lens=(5, 9, 3, 14)), seeded, dict()),
+    "prefix_shared": ([SHARED + p for p in make_prompts(seed=4, lens=(3, 6, 2, 5))],
+                      seeded, dict(token_budget=16)),
+    # verify grants of 1 + 2 columns: head rows 2 x 3 = 6 < capacity 17
+    "spec_gathered": (make_prompts(), seeded, dict(token_budget=16, draft_k=2)),
+    # capacity 5 <= 6 head rows: the gather is skipped
+    "spec_skipped": (make_prompts(), seeded, dict(token_budget=4, draft_k=2)),
+}
+
+
+class TestHeadRowGather:
+    """A packed program wider than ``ContinuousBatcher.head_rows`` sends
+    only the rows that can emit through the LM head and the sampler; the
+    streams are token-identical to the same engine fed every row, and to
+    the dense engine (the parity oracle)."""
+
+    @staticmethod
+    def _packed(params, prompts, sampling, all_rows=False, token_budget=None,
+                draft_k=0):
+        spec = SpecConfig(NGramProposer(), k=draft_k) if draft_k else None
+        eng = ContinuousBatcher(params, CFG, batch_slots=2, max_len=32,
+                                chunk_size=4, packed=True, cache="paged",
+                                page_size=4, token_budget=token_budget, spec=spec)
+        if all_rows:
+            eng.head_rows = eng.packed_capacity  # no program is wider
+        for i, p in enumerate(prompts):
+            eng.submit(Request(uid=i, prompt=list(p), max_new_tokens=8,
+                               sampling=sampling(i)))
+        eng.run()
+        return eng
+
+    @pytest.mark.parametrize("case", sorted(HEAD_CASES))
+    def test_streams_match_all_rows_and_dense(self, params, case):
+        prompts, sampling, kw = HEAD_CASES[case]
+        eng = self._packed(params, prompts, sampling, **kw)
+        every = self._packed(params, prompts, sampling, all_rows=True, **kw)
+        dense = run_engine(params, prompts, sampling=sampling, chunk_size=4,
+                           token_budget=kw.get("token_budget"))
+        assert outputs(eng) == outputs(every) == outputs(dense)
+        gathered = [st for st in eng.step_stats if st.head_rows < st.rows]
+        assert all(st.head_rows == st.rows for st in every.step_stats)
+        if case == "spec_skipped":
+            assert eng.packed_capacity <= eng.head_rows and not gathered
+        else:
+            assert gathered and all(st.head_rows == eng.head_rows for st in gathered)
+        if case.startswith("spec"):
+            assert eng.stats_summary()["draft_tokens"] > 0
+        if case == "prefix_shared":
+            assert eng.stats_summary()["shared_tokens"] > 0
+        if case in ("chunk_boundary", "mid_chunk"):
+            on_boundary = {len(p) % 4 == 0 for p in prompts}
+            assert on_boundary == {case == "chunk_boundary"}
+
+
+# ---------------------------------------------------------------------------
 # sampler units: masking, validation, residual form
 # ---------------------------------------------------------------------------
 
